@@ -3,8 +3,9 @@
 Prints ``name,us_per_call,derived`` CSV (brief contract) and writes the
 full curve data to results/benchmarks/*.csv so EXPERIMENTS.md can quote
 any point. Analytic figures time the accountant; system rows time the
-actual jitted server paths on this host (CPU — TPU numbers come from the
-dry-run roofline, EXPERIMENTS.md §Roofline).
+actual jitted server paths on this host. These timings are CPU-host
+numbers (jnp oracles and interpret-mode Pallas), not device numbers: no
+row here measures a TPU. ``python chip_smoke.py`` is the on-chip check.
 
 Run: PYTHONPATH=src python -m benchmarks.run [--smoke] [--only NAME,...]
 
@@ -29,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import accounting as acc
 from repro.core import make_scheme
 from repro.db import make_synthetic_store
@@ -1048,6 +1050,9 @@ def sharded_ingest() -> List[Row]:
         os.path.join(os.path.dirname(__file__), "..", "src")
     )
     env.pop("XLA_FLAGS", None)  # the worker sets its own
+    # a forced-CPU simulation: this parent may hold the chip, and a child
+    # that reached for it would fail or hang
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, worker] + (["--smoke"] if SMOKE else []),
         capture_output=True, text=True, env=env, timeout=540,
@@ -1089,7 +1094,7 @@ def sharded_ingest() -> List[Row]:
     _bench("sharded_ingest", r["burst_rows"], per_burst, ratio)
     return [(
         "sharded_ingest", per_burst * 1e6,
-        f"full/touched={ratio:.2f}x;touched_shards="
+        f"platform={r['platform']};full/touched={ratio:.2f}x;touched_shards="
         f"{r['store_shards_touched']}/{r['store_shards_total']};"
         f"mesh_kept={r['mesh_shards_kept']};plans_dropped=0;torn=0",
     )]
@@ -1113,6 +1118,7 @@ def main(argv=None) -> None:
                          "(default: all); see results/README.md")
     args = ap.parse_args(argv)
     SMOKE = args.smoke
+    use_compile_cache()
     fns = ALL
     if args.only:
         by_name = {fn.__name__: fn for fn in ALL}

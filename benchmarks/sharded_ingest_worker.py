@@ -14,9 +14,12 @@ ingest-to-first-answer wall. Asserted here, not in the parent: the two
 modes' answers are bit-identical every burst (zero torn), and the
 touched mode never drops a cached ExecutionPlan.
 
+It is a simulation on 8 forced CPU devices (the parent pins
+``JAX_PLATFORMS=cpu`` too), and its JSON says so under ``platform``.
+
 Prints one JSON object on the last stdout line for the parent to parse.
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
         PYTHONPATH=src python benchmarks/sharded_ingest_worker.py [--smoke]
 """
 
@@ -26,6 +29,7 @@ import os
 import time
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax
 import jax.numpy as jnp
@@ -135,6 +139,7 @@ def main() -> None:
 
     pm = inc.planner.metrics
     out = {
+        "platform": jax.devices()[0].platform,
         "n": n,
         "bursts": bursts,
         "burst_rows": burst_rows,

@@ -35,7 +35,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import current_mesh, mesh_axis_names
@@ -85,7 +85,7 @@ def _sharded_lookup(table: jnp.ndarray, ids: jnp.ndarray, vocab_logical: str):
         mesh=mesh,
         in_specs=(P(vaxes, *([None] * (table.ndim - 1))), ids_spec),
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )
     def _lookup(tbl, idl):
         lin = jnp.int32(0)
@@ -173,7 +173,7 @@ def sharded_record_lookup(packed: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
         in_specs=(P(raxes, *([None] * (packed.ndim - 1))),
                   P(*([None] * ids.ndim))),
         out_specs=P(*([None] * (ids.ndim + 1))),
-        check_rep=False,
+        check_vma=False,
     )
     def _lookup(db, idl):
         lin = jnp.int32(0)

@@ -25,7 +25,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import current_mesh, mesh_axis_names
@@ -122,7 +122,7 @@ def flash_decode(
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=q_spec,
-        check_rep=False,
+        check_vma=False,
     )
     def _decode(qc, kc, vc, ln, *rest):
         win = rest[0] if has_window else None

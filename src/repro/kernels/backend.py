@@ -76,6 +76,7 @@ from repro.db import packing
 from repro.db.store import RecordStore
 from repro.kernels import ops, ref
 from repro.kernels.fused import (
+    LANES,
     fused_block_w,
     fused_gather_fold,
     fused_multi_gather_fold,
@@ -94,6 +95,7 @@ __all__ = [
     "AutotuneTable",
     "autotune_table",
     "device_fingerprint",
+    "device_memory_limit",
     "load_autotune",
     "dump_autotune",
     "PlanCandidate",
@@ -294,6 +296,17 @@ def device_fingerprint() -> Dict[str, str]:
         "platform": str(dev.platform),
         "device_kind": str(getattr(dev, "device_kind", "") or dev.platform),
     }
+
+
+def device_memory_limit() -> Optional[int]:
+    """Bytes one device of this host can hold (``memory_stats()
+    ["bytes_limit"]`` on TPU), or None where the backend does not say —
+    the planner keeps a candidate whose operand cannot fit out of every
+    race."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return None
+    return int(dev.memory_stats()["bytes_limit"])
 
 
 class AutotuneTable:
@@ -570,8 +583,11 @@ class KernelPlanner:
     ``seed`` fixes the bench-payload PRNG so a search over the same
     cells is reproducible; ``vmem_budget_bytes`` overrides the
     device-derived fused VMEM gate (``PIRConfig.fused_vmem_budget_bytes``
-    threads through here); ``measure`` swaps the microbenchmark function
-    (tests inject deterministic timers).
+    threads through here); ``memory_limit_bytes`` overrides the
+    device's memory limit (:func:`device_memory_limit`, read once here),
+    which keeps candidates that cannot fit out of every race;
+    ``measure`` swaps the microbenchmark function (tests inject
+    deterministic timers).
     """
 
     # the sparse gather forms only pay while the index budget stays
@@ -588,6 +604,7 @@ class KernelPlanner:
         parity_min_batch: Optional[int] = None,
         seed: int = 0,
         vmem_budget_bytes: Optional[int] = None,
+        memory_limit_bytes: Optional[int] = None,
         measure: Optional[Callable[..., float]] = None,
     ):
         self.backend = get_backend(backend)
@@ -596,6 +613,10 @@ class KernelPlanner:
         self._parity_min_batch = parity_min_batch
         self._seed = int(seed)
         self._vmem_budget = vmem_budget_bytes
+        self._memory_limit = (
+            memory_limit_bytes if memory_limit_bytes is not None
+            else device_memory_limit()
+        )
         self._measure = measure if measure is not None else _measure_us
         self._planes: Optional[jnp.ndarray] = None
         self._plans: Dict[Tuple, ExecutionPlan] = {}
@@ -658,6 +679,18 @@ class KernelPlanner:
             n_eff, self.store.words, budget_bytes=self._vmem_budget
         )
 
+    def _fits(self, extra_bytes: int) -> bool:
+        """Whether a candidate that needs ``extra_bytes`` beside the
+        store fits one device (always, where the device does not say)."""
+        limit = self._memory_limit
+        return limit is None or self.store.nbytes + extra_bytes <= limit
+
+    def _parity_fits(self, n_eff: int) -> bool:
+        """The parity path's operand: float32 bitplanes plus the bfloat16
+        copy the kernel feeds the MXU, 6 bytes per record bit — about
+        74 GB at CT scale, so a 16 GB chip never races it."""
+        return self._fits(n_eff * self.store.record_bits * 6)
+
     # ------------------------------------------------------------ executors
     def _operand(self, path: str) -> jnp.ndarray:
         """The kernel operand for a path, from the *current* store — read
@@ -705,21 +738,23 @@ class KernelPlanner:
         layout, plus the ref baseline under ``auto``."""
         out: List[PlanCandidate] = []
         if cell.theta is None:  # dense-mask family: fold vs parity
+            parity = self._parity_fits(cell.n_eff)
             for impl in self._impl_candidates(cell.impl):
                 out.append(PlanCandidate("fold", impl))
-                out.append(PlanCandidate("parity", impl))
+                if parity:
+                    out.append(PlanCandidate("parity", impl))
             return out
         # sparse family
         for impl in self._impl_candidates(cell.impl):
             if impl == "ref":
-                out.append(PlanCandidate("sparse_ref", "ref"))
+                # the oracle gathers [bucket, m, W] rows before folding
+                if self._fits(
+                    cell.bucket * (cell.m_budget or 0) * self.store.words * 4
+                ):
+                    out.append(PlanCandidate("sparse_ref", "ref"))
                 continue
-            w = self.store.words
-            bw_max = self._fused_bw(cell.n_eff)
-            fused_bws = [bw_max] if bw_max else []
-            if bw_max // 2 >= 8:  # a narrower tile, if one is distinct
-                fused_bws.append(bw_max // 2)
-            for bw in fused_bws:
+            bw = self._fused_bw(cell.n_eff)
+            if bw:
                 for go in ("qw", "wq"):
                     out.append(PlanCandidate(
                         "sparse_fused", impl,
@@ -737,7 +772,10 @@ class KernelPlanner:
                             (("block_w", bw), ("grid_order", go),
                              ("k_max", cell.k_max)),
                         ))
-            for bw in sorted({min(128, w), min(32, w)}, reverse=True):
+            # word-blocks the TPU compiler accepts: the whole record, and
+            # one 128-lane vreg when the record is wider
+            w = self.store.words
+            for bw in ([w, LANES] if w > LANES else [w]):
                 for go in ("qwm", "wqm"):
                     out.append(PlanCandidate(
                         "sparse_pair", impl,
@@ -753,8 +791,8 @@ class KernelPlanner:
         the search). Returns (path, impl, blocks)."""
         if cell.theta is None:
             qstar = self._model_crossover()
-            path = "parity" if cell.bucket >= qstar else "fold"
-            return path, cell.impl, {}
+            parity = cell.bucket >= qstar and self._parity_fits(cell.n_eff)
+            return ("parity" if parity else "fold"), cell.impl, {}
         if cell.impl == "ref":
             return "sparse_ref", "ref", {}
         bw = self._fused_bw(cell.n_eff)

@@ -27,16 +27,21 @@ measurement, not by napkin.
 
 The price is VMEM residency: the db word-block is [n, BW] uint32, so the
 kernel only applies when ``n·BW·4`` fits the VMEM budget —
-:func:`fused_block_w` picks the widest power-of-two BW that fits and
-returns 0 when none does, which is exactly the signal the execution
-planner (``repro.kernels.backend``) uses to fall back to the unfused
-pair. The budget derives from the *local* device
-(:func:`fused_vmem_budget`: half the device's VMEM, by ``device_kind``),
-falling back to the v5e-shaped :data:`FUSED_VMEM_BUDGET_BYTES` constant
-off-TPU — so the gate fires where this host's VMEM says it should, not
-where a v5e's would. At CT scale (n = 10⁶) the fused form only applies
-per record *shard*; single-host million-record stores take the streaming
-pair.
+:func:`fused_block_w` picks the widest lane-legal BW that fits (W
+itself, or a multiple of 128 words: the TPU compiler refuses narrower
+slices of a wider row) and returns 0 when none does, which is exactly
+the signal the execution planner (``repro.kernels.backend``) uses to
+fall back to the unfused pair. The budget derives from the *local*
+device (:func:`fused_vmem_budget`: a quarter of the device's VMEM, by
+``device_kind``, since the slab is double-buffered), falling back to the
+:data:`FUSED_VMEM_BUDGET_BYTES` constant off-TPU; a TPU kind the table
+does not list is an error. At CT scale (n = 10⁶) the fused form only
+applies per record *shard*; single-host million-record stores take the
+streaming pair.
+
+Outputs follow :mod:`~repro.kernels.gather_xor`'s layout: one [8, BW]
+tile per query whose row 0 holds the answer, folded by the wrapper, and
+index lists longer than scalar memory holds run as XOR-ed chunks.
 
 Bit-identity: fused(db, idx) == gather_xor(db, idx) == xor_fold(db, mask)
 == the jnp oracle, proven exactly in tests/test_kernels.py and swept by
@@ -53,6 +58,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiles import SUBLANES, fold_sublanes, xor_over_index_chunks
+
 __all__ = [
     "fused_gather_fold",
     "fused_multi_gather_fold",
@@ -64,76 +71,97 @@ __all__ = [
 
 DEFAULT_BLOCK_W = 128
 
-# Fallback VMEM budget the fused db word-block may occupy (half of a v5e
-# core's 16 MiB, leaving room for the output block, the loop state and
-# double buffering) — used when the local device's VMEM is unknown
+# VMEM budget the fused db word-block may occupy off-TPU (interpret mode)
 FUSED_VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
-# per-core VMEM by TPU device kind (bytes). Most generations carry
-# 16 MiB of VMEM per core; v4 doubles it. Matching is by substring of
-# jax's device_kind string ("TPU v4", "TPU v5 lite", ...); unknown kinds
-# fall back to the 16 MiB default, non-TPU hosts to the constant above.
+# per-core VMEM by TPU device kind (jax's ``device_kind`` string), each
+# confirmed by compiling the fused kernel for that chip. A kind missing
+# here raises: a wrong size would let the planner propose a kernel the
+# compiler refuses, or hide one that fits.
 _TPU_VMEM_BYTES = {
-    "v4": 32 * 1024 * 1024,
+    "TPU v5 lite": 128 * 1024 * 1024,  # v5e
 }
-_TPU_VMEM_DEFAULT = 16 * 1024 * 1024
+
+# lanes per vreg: a block narrower than the row must be a multiple of it
+LANES = 128
+# VMEM the fused kernels reserve beyond the double-buffered slab
+_VMEM_HEADROOM = 4 * 1024 * 1024
 
 
 def fused_vmem_budget() -> int:
     """VMEM budget for the fused db word-block, derived from the local
-    device: half the device's per-core VMEM on TPU (the other half stays
-    free for the output block, loop state and double buffering — the
-    same split the old hardcoded constant assumed for a v5e), the
-    :data:`FUSED_VMEM_BUDGET_BYTES` fallback anywhere else. The
-    execution planner threads a ``PIRConfig.fused_vmem_budget_bytes``
+    device: a quarter of the device's per-core VMEM on TPU (the slab is
+    double-buffered, and the rest stays free for the output tiles and the
+    loop state), the :data:`FUSED_VMEM_BUDGET_BYTES` constant anywhere
+    else. Raises for a TPU kind :data:`_TPU_VMEM_BYTES` does not list.
+    The execution planner threads a ``PIRConfig.fused_vmem_budget_bytes``
     override past this entirely."""
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         return FUSED_VMEM_BUDGET_BYTES
-    kind = getattr(dev, "device_kind", "") or ""
-    vmem = _TPU_VMEM_DEFAULT
-    for sub, size in _TPU_VMEM_BYTES.items():
-        if sub in kind.lower():
-            vmem = size
-            break
-    return vmem // 2
+    kind = str(getattr(dev, "device_kind", ""))
+    if kind not in _TPU_VMEM_BYTES:
+        raise ValueError(
+            f"no VMEM size known for TPU kind {kind!r}; add it to "
+            f"repro.kernels.fused._TPU_VMEM_BYTES after confirming it by "
+            f"a compile for that chip"
+        )
+    return _TPU_VMEM_BYTES[kind] // 4
 
 
 def fused_block_w(n: int, w: int, *, block_w: int = DEFAULT_BLOCK_W,
                   budget_bytes: Optional[int] = None) -> int:
-    """Widest power-of-two word-block ≤ min(block_w, W) whose [n, BW]
-    uint32 db slab fits the VMEM budget; 0 when nothing ≥ min(8, W)
-    words fits (caller must fall back to the unfused streaming pair — a
-    lane-starved sliver block would waste the VPU even if it technically
-    fit). ``budget_bytes=None`` derives the budget from the local device
-    (:func:`fused_vmem_budget`)."""
+    """Widest lane-legal word-block ≤ min(block_w, W) whose [n, BW]
+    uint32 db slab fits the VMEM budget: W itself, or a power-of-two
+    multiple of 128 words. 0 when none fits (caller must fall back to the
+    unfused streaming pair). ``budget_bytes=None`` derives the budget
+    from the local device (:func:`fused_vmem_budget`)."""
     if budget_bytes is None:
         budget_bytes = fused_vmem_budget()
-    cap = max(1, min(block_w, w))
-    bw = 1 << (cap.bit_length() - 1)  # round down to a power of two
-    floor = min(8, bw)
-    while bw > floor and n * bw * 4 > budget_bytes:
-        bw //= 2
-    return bw if n * bw * 4 <= budget_bytes else 0
+    cap = min(block_w, w)
+    widths = {w} if cap == w else set()
+    bw = LANES
+    while bw <= cap:
+        widths.add(bw)
+        bw *= 2
+    for bw in sorted(widths, reverse=True):
+        if n * bw * 4 <= budget_bytes:
+            return bw
+    return 0
 
 
-def _kernel(idx_ref, db_ref, out_ref, *, b_axis: int):
-    b = pl.program_id(b_axis)
-    m = idx_ref.shape[1]
-    bw = out_ref.shape[1]
+def _vmem_limit(n: int, bw: int, out_rows: int) -> int:
+    """Scoped VMEM a fused call needs: the double-buffered [n, BW] slab
+    and output tiles, plus headroom."""
+    return 2 * (n * bw + out_rows * SUBLANES * bw) * 4 + _VMEM_HEADROOM
+
+
+def _fold_rows(idx_ref, db_ref, row: int, m: int, bw: int) -> jnp.ndarray:
+    """[1, BW] XOR of the db rows that index row ``row`` of the (flat)
+    index list selects; padded (-1) slots clamp to row 0 and are masked
+    out of the fold."""
 
     def body(i, acc):
-        j = idx_ref[b, i]
-        # gather: one dynamic row out of the VMEM-resident word-block;
-        # padded (-1) slots clamp to row 0 and are masked out of the fold
-        row = db_ref[pl.ds(jnp.maximum(j, 0), 1), :]
-        return acc ^ jnp.where(j >= 0, row, jnp.uint32(0))
+        j = idx_ref[row * m + i]
+        r = db_ref[pl.ds(jnp.maximum(j, 0), 1), :]
+        return acc ^ jnp.where(j >= 0, r, jnp.uint32(0))
 
-    # xor+fold: register accumulator across the in-kernel index walk —
-    # the single output write below is the whole answer for this block
-    out_ref[...] = jax.lax.fori_loop(
-        0, m, body, jnp.zeros((1, bw), jnp.uint32)
-    )
+    return jax.lax.fori_loop(0, m, body, jnp.zeros((1, bw), jnp.uint32))
+
+
+def _answer_tile(acc: jnp.ndarray) -> jnp.ndarray:
+    """[1, BW] answer -> [8, BW] output tile holding it in row 0."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, acc.shape[1]), 0)
+    return jnp.where(rows == 0, acc, jnp.uint32(0))
+
+
+def _kernel(idx_ref, db_ref, out_ref, *, b_axis: int, m: int):
+    # gather+xor+fold: a register accumulator across the in-kernel index
+    # walk — the single output write below is the whole answer for this
+    # block
+    acc = _fold_rows(idx_ref, db_ref, pl.program_id(b_axis), m,
+                     out_ref.shape[-1])
+    out_ref[...] = _answer_tile(acc)
 
 
 @functools.partial(
@@ -156,44 +184,49 @@ def fused_gather_fold(
     if grid_order not in ("qw", "wq"):
         raise ValueError(f"grid_order must be 'qw' or 'wq', got {grid_order!r}")
     n, w = db.shape
-    q, m = idx.shape
+    q = idx.shape[0]
 
     bw = min(block_w, w)
-    wp = -w % bw
-    db_p = jnp.pad(db, ((0, 0), (0, wp)))
-    wblocks = (w + wp) // bw
+    wblocks = -(-w // bw)  # a ragged last word-block reads past W
 
     if grid_order == "qw":
         # queries outer: the db word-block is re-fetched per query
         grid = (q, wblocks)
         db_map = lambda b, j, idx_ref: (0, j)
-        out_map = lambda b, j, idx_ref: (b, j)
+        out_map = lambda b, j, idx_ref: (b, 0, j)
         b_axis = 0
     else:
         # word-blocks outer: one resident db block answers every query
         # before the next block is DMA'd in
         grid = (wblocks, q)
         db_map = lambda j, b, idx_ref: (0, j)
-        out_map = lambda j, b, idx_ref: (b, j)
+        out_map = lambda j, b, idx_ref: (b, 0, j)
         b_axis = 1
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            # the whole record axis of one word-block, VMEM-resident for
-            # the duration of the in-kernel index walk
-            pl.BlockSpec((n, bw), db_map),
-        ],
-        out_specs=pl.BlockSpec((1, bw), out_map),
-    )
-    out = pl.pallas_call(
-        functools.partial(_kernel, b_axis=b_axis),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((q, w + wp), jnp.uint32),
-        interpret=interpret,
-    )(idx, db_p)
-    return out[:, :w]
+    def call(ix: jnp.ndarray) -> jnp.ndarray:
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                # the whole record axis of one word-block, VMEM-resident
+                # for the duration of the in-kernel index walk
+                pl.BlockSpec((n, bw), db_map),
+            ],
+            out_specs=pl.BlockSpec((None, SUBLANES, bw), out_map),
+        )
+        return pl.pallas_call(
+            functools.partial(_kernel, b_axis=b_axis, m=ix.shape[1]),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(
+                (q, SUBLANES, wblocks * bw), jnp.uint32
+            ),
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_vmem_limit(n, bw, 1)
+            ),
+            interpret=interpret,
+        )(ix.reshape(-1), db)
+
+    return fold_sublanes(xor_over_index_chunks(call, idx))[:, :w]
 
 
 # --------------------------------------------------------------------------
@@ -212,22 +245,16 @@ def jagged_row_mask(offsets: jnp.ndarray, k_max: int, rows: int) -> jnp.ndarray:
 
 
 def _multi_kernel(off_ref, idx_ref, db_ref, out_ref, *, b_axis: int,
-                  k_max: int):
+                  k_max: int, m: int):
     r = pl.program_id(b_axis)
-    m = idx_ref.shape[1]
-    bw = out_ref.shape[1]
+    bw = out_ref.shape[-1]
     # the jagged descriptor rides in scalar memory: this request's live
     # column count bounds which of its k_max rows carry real queries
     count = off_ref[r + 1] - off_ref[r]
 
     def fold(i, carry):
-        def body(l, acc):
-            j = idx_ref[r * k_max + i, l]
-            row = db_ref[pl.ds(jnp.maximum(j, 0), 1), :]
-            return acc ^ jnp.where(j >= 0, row, jnp.uint32(0))
-
-        acc = jax.lax.fori_loop(0, m, body, jnp.zeros((1, bw), jnp.uint32))
-        out_ref[pl.ds(i, 1), :] = jnp.where(i < count, acc, jnp.uint32(0))
+        acc = _fold_rows(idx_ref, db_ref, r * k_max + i, m, bw)
+        out_ref[i] = _answer_tile(jnp.where(i < count, acc, jnp.uint32(0)))
         return carry
 
     # one grid step answers ALL of this request's indices: the db
@@ -266,7 +293,7 @@ def fused_multi_gather_fold(
     if grid_order not in ("rw", "wr"):
         raise ValueError(f"grid_order must be 'rw' or 'wr', got {grid_order!r}")
     n, w = db.shape
-    b, m = idx.shape
+    b = idx.shape[0]
     if k_max < 1 or b % k_max:
         raise ValueError(f"idx rows {b} not a multiple of k_max={k_max}")
     r_count = b // k_max
@@ -276,33 +303,41 @@ def fused_multi_gather_fold(
         )
 
     bw = min(block_w, w)
-    wp = -w % bw
-    db_p = jnp.pad(db, ((0, 0), (0, wp)))
-    wblocks = (w + wp) // bw
+    wblocks = -(-w // bw)  # a ragged last word-block reads past W
 
     if grid_order == "rw":
         grid = (r_count, wblocks)
         db_map = lambda r, j, off_ref, idx_ref: (0, j)
-        out_map = lambda r, j, off_ref, idx_ref: (r, j)
+        out_map = lambda r, j, off_ref, idx_ref: (r, 0, j)
         b_axis = 0
     else:
         grid = (wblocks, r_count)
         db_map = lambda j, r, off_ref, idx_ref: (0, j)
-        out_map = lambda j, r, off_ref, idx_ref: (r, j)
+        out_map = lambda j, r, off_ref, idx_ref: (r, 0, j)
         b_axis = 1
+    offsets = jnp.asarray(offsets, jnp.int32)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n, bw), db_map),
-        ],
-        out_specs=pl.BlockSpec((k_max, bw), out_map),
-    )
-    out = pl.pallas_call(
-        functools.partial(_multi_kernel, b_axis=b_axis, k_max=k_max),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, w + wp), jnp.uint32),
-        interpret=interpret,
-    )(jnp.asarray(offsets, jnp.int32), idx, db_p)
-    return out[:, :w]
+    def call(ix: jnp.ndarray) -> jnp.ndarray:
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((n, bw), db_map),
+            ],
+            out_specs=pl.BlockSpec((k_max, SUBLANES, bw), out_map),
+        )
+        return pl.pallas_call(
+            functools.partial(
+                _multi_kernel, b_axis=b_axis, k_max=k_max, m=ix.shape[1]
+            ),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(
+                (b, SUBLANES, wblocks * bw), jnp.uint32
+            ),
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_vmem_limit(n, bw, k_max)
+            ),
+            interpret=interpret,
+        )(offsets, ix.reshape(-1), db)
+
+    return fold_sublanes(xor_over_index_chunks(call, idx))[:, :w]

@@ -8,15 +8,23 @@ analogue of the CPU implementation's pointer-chasing gather.
 
 Layout: idx [q, m] int32 (selected record ids per query, padded with -1;
 m = ceil(θ·n·slack) is static). Grid: (q, w_blocks, m) by default; the
-output block [1, BW] stays in VMEM across the m innermost steps while
-selected record blocks are DMA'd in; padded slots skip the XOR via
-@pl.when. ``grid_order="wqm"`` swaps the two outer axes (word-blocks
-outer, queries middle) — the m accumulation axis always stays innermost,
-so both orders write each output block exactly once and are bit-identical;
-which order streams better is the execution planner's autotune search to
-settle (DESIGN.md §Execution backends), along with the ``block_w`` tile.
+output tile stays in VMEM across the m innermost steps while selected
+record blocks are DMA'd in. ``grid_order="wqm"`` swaps the two outer axes
+(word-blocks outer, queries middle) — the m accumulation axis always
+stays innermost, so both orders write each output block exactly once and
+are bit-identical; which order streams better is the execution planner's
+autotune search to settle (DESIGN.md §Execution backends), along with the
+``block_w`` tile.
 
-Per-step VMEM: db row block 1·BW·4 + out 1·BW·4 ≈ 1 KiB at BW=128 — the
+TPU tiling: a block's last two dims must be multiples of (8, 128) or span
+the array. So each step fetches the 8-row tile holding the selected
+record and XORs only that record's row into the matching row of an
+[8, BW] output tile (padded -1 slots keep none); the wrapper folds the 8
+rows. The index list rides in scalar memory, which holds 1 MiB on a v5e:
+:func:`~repro.kernels.tiles.xor_over_index_chunks` splits a longer list
+into calls that each fit scalar memory and XORs their answers.
+
+Per-step VMEM: db tile 8·BW·4 + out 8·BW·4 = 8 KiB at BW=128 — the
 kernel is pure DMA-bound streaming, as the cost model says it should be.
 """
 
@@ -29,12 +37,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiles import SUBLANES, fold_sublanes, xor_over_index_chunks
+
 __all__ = ["gather_xor", "indices_from_mask"]
 
 DEFAULT_BLOCK_W = 128
 
 
-def _kernel(idx_ref, db_ref, out_ref, *, b_axis: int):
+def _kernel(idx_ref, db_ref, out_ref, *, b_axis: int, m: int):
     b = pl.program_id(b_axis)
     i = pl.program_id(2)
 
@@ -42,9 +52,10 @@ def _kernel(idx_ref, db_ref, out_ref, *, b_axis: int):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(idx_ref[b, i] >= 0)
-    def _fold():
-        out_ref[...] = out_ref[...] ^ db_ref[...]
+    j = idx_ref[b * m + i]
+    rows = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
+    keep = (rows == j % SUBLANES) & (j >= 0)
+    out_ref[...] = out_ref[...] ^ jnp.where(keep, db_ref[...], jnp.uint32(0))
 
 
 @functools.partial(
@@ -63,44 +74,50 @@ def gather_xor(
         raise ValueError(
             f"grid_order must be 'qwm' or 'wqm', got {grid_order!r}"
         )
-    n, w = db.shape
-    q, m = idx.shape
+    w = db.shape[1]
+    q = idx.shape[0]
 
+    # the store is never copied: ragged edge tiles read past its end,
+    # and the row select and the final slice discard that
     bw = min(block_w, w)
-    wp = -w % bw
-    db_p = jnp.pad(db, ((0, 0), (0, wp)))
-    wblocks = (w + wp) // bw
+    wblocks = -(-w // bw)
 
     if grid_order == "qwm":
-        grid = (q, wblocks, m)
         b_axis, j_axis = 0, 1
     else:
-        grid = (wblocks, q, m)
         b_axis, j_axis = 1, 0
 
-    def db_map(*args):
-        ids, idx_ref = args[:3], args[3]
-        # one record row per innermost step, selected by the prefetched
-        # index; padded (-1) slots clamp to row 0 and are skipped in-kernel
-        return (jnp.maximum(idx_ref[ids[b_axis], ids[2]], 0), ids[j_axis])
+    def call(ix: jnp.ndarray) -> jnp.ndarray:
+        c = ix.shape[1]
+        grid = (q, wblocks, c) if grid_order == "qwm" else (wblocks, q, c)
 
-    def out_map(*args):
-        ids = args[:3]
-        return (ids[b_axis], ids[j_axis])
+        def db_map(*args):
+            ids, idx_ref = args[:3], args[3]
+            # the 8-row tile holding the selected record; padded (-1)
+            # slots clamp to tile 0 and keep no row in-kernel
+            j = idx_ref[ids[b_axis] * c + ids[2]]
+            return (jnp.maximum(j, 0) // SUBLANES, ids[j_axis])
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, bw), db_map)],
-        out_specs=pl.BlockSpec((1, bw), out_map),
-    )
-    out = pl.pallas_call(
-        functools.partial(_kernel, b_axis=b_axis),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((q, w + wp), jnp.uint32),
-        interpret=interpret,
-    )(idx, db_p)
-    return out[:, :w]
+        def out_map(*args):
+            ids = args[:3]
+            return (ids[b_axis], 0, ids[j_axis])
+
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[pl.BlockSpec((SUBLANES, bw), db_map)],
+            out_specs=pl.BlockSpec((None, SUBLANES, bw), out_map),
+        )
+        return pl.pallas_call(
+            functools.partial(_kernel, b_axis=b_axis, m=c),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(
+                (q, SUBLANES, wblocks * bw), jnp.uint32
+            ),
+            interpret=interpret,
+        )(ix.reshape(-1), db)
+
+    return fold_sublanes(xor_over_index_chunks(call, idx))[:, :w]
 
 
 @functools.partial(jax.jit, static_argnames=("m",))

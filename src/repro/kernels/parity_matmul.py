@@ -27,10 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu scratch shapes work in interpret mode too
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["parity_matmul"]
 
@@ -54,7 +51,10 @@ def _kernel(mask_ref, planes_ref, out_ref, acc_ref):
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _epilogue():
-        out_ref[...] = jnp.mod(acc_ref[...], 2.0).astype(jnp.uint8)
+        # parity in int32, then narrow: the TPU compiler has no direct
+        # float32 -> uint8 cast
+        bits = acc_ref[...].astype(jnp.int32) & 1
+        out_ref[...] = bits.astype(jnp.uint8)
 
 
 @functools.partial(
@@ -85,11 +85,6 @@ def parity_matmul(
     planes_p = jnp.pad(planes.astype(jnp.bfloat16), ((0, np_), (0, bp)))
 
     grid = ((q + qp) // bq, (b + bp) // bb, (n + np_) // bn)
-    scratch = (
-        [pltpu.VMEM((bq, bb), jnp.float32)]
-        if pltpu is not None
-        else [pl.MemorySpace.ANY]  # pragma: no cover
-    )
     out = pl.pallas_call(
         _kernel,
         grid=grid,
@@ -99,7 +94,7 @@ def parity_matmul(
         ],
         out_specs=pl.BlockSpec((bq, bb), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((q + qp, b + bp), jnp.uint8),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((bq, bb), jnp.float32)],
         interpret=interpret,
     )(mask_p, planes_p)
     return out[:q, :b]

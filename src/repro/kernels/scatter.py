@@ -83,9 +83,9 @@ def scatter_rows(
     if m == 0:
         return db
     bn = max(1, min(block_n, n))
-    n_pad = -n % bn
-    db_p = jnp.pad(db, ((0, n_pad), (0, 0)))
-    grid = ((n + n_pad) // bn,)
+    # no padded copy of the store: the ragged last block reads past the
+    # end and its write-back is clipped to the array
+    grid = (-(-n // bn),)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -97,10 +97,9 @@ def scatter_rows(
         ],
         out_specs=pl.BlockSpec((bn, w), lambda i, rows_ref: (i, 0)),
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_kernel, bn=bn),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n + n_pad, w), db.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, w), db.dtype),
         interpret=interpret,
-    )(rows.astype(jnp.int32), vals.astype(db.dtype), db_p)
-    return out[:n]
+    )(rows.astype(jnp.int32), vals.astype(db.dtype), db)

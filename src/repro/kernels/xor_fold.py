@@ -13,8 +13,14 @@ small query batches (latency serving). Large batches use parity_matmul
 Grid: (q_blocks, w_blocks, n_blocks), n innermost so the output block
 stays resident in VMEM while records stream through.
 
+The TPU compiler lowers no XOR ``reduce``, so the record axis folds by
+halving: the selected [BQ, BN, BW] rows XOR pairwise down to one
+8-sublane tile per query, the kernel accumulates those [BQ, 8, BW] tiles,
+and the wrapper folds the last 8 rows with an XLA reduce. BN is therefore
+8 times a power of two.
+
 VMEM working set per step (defaults BQ=8, BN=256, BW=128):
-  mask 8·256·4 + db 256·128·4 + out 8·128·4 + select temp 8·256·128·4
+  mask 8·256·4 + db 256·128·4 + out 8·8·128·4 + select temp 8·256·128·4
   ≈ 1.2 MiB  « 16 MiB VMEM.
 """
 
@@ -26,11 +32,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiles import SUBLANES, fold_sublanes
+
 __all__ = ["xor_fold"]
 
 DEFAULT_BLOCK_Q = 8
 DEFAULT_BLOCK_N = 256
 DEFAULT_BLOCK_W = 128
+
+
+def fold_halves(x: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """XOR the two halves of ``axis`` together until it is 8 long (the
+    axis length must be 8 times a power of two)."""
+    while x.shape[axis] > SUBLANES:
+        h = x.shape[axis] // 2
+        x = (jax.lax.slice_in_dim(x, 0, h, axis=axis)
+             ^ jax.lax.slice_in_dim(x, h, 2 * h, axis=axis))
+    return x
 
 
 def _kernel(mask_ref, db_ref, out_ref):
@@ -43,8 +61,7 @@ def _kernel(mask_ref, db_ref, out_ref):
     m = mask_ref[...]  # [BQ, BN] int32
     db = db_ref[...]  # [BN, BW] uint32
     sel = jnp.where(m[:, :, None] != 0, db[None, :, :], jnp.uint32(0))
-    folded = jax.lax.reduce(sel, jnp.uint32(0), jax.lax.bitwise_xor, (1,))
-    out_ref[...] = out_ref[...] ^ folded
+    out_ref[...] = out_ref[...] ^ fold_halves(sel, 1)  # [BQ, 8, BW]
 
 
 @functools.partial(
@@ -64,18 +81,17 @@ def xor_fold(
     n2, w = db.shape
     assert n == n2, (mask.shape, db.shape)
 
-    bq, bn, bw = min(block_q, q), min(block_n, n), min(block_w, w)
-    # pad every axis to a block multiple (ragged edges handled by padding
-    # with zeros: XOR identity, mask 0 selects nothing)
-    qp, np_, wp = (-q % bq), (-n % bn), (-w % bw)
+    bq, bw = min(block_q, q), min(block_w, w)
+    # BN: the smallest 8·2^k covering min(block_n, n), so the halving
+    # fold always lands on one 8-row tile
+    bn = SUBLANES << (-(-min(block_n, n) // SUBLANES) - 1).bit_length()
+    # pad the mask to whole blocks with zeros (mask 0 selects nothing);
+    # the store is never copied: its ragged edge blocks read past the
+    # end, and the zero mask columns and the final slice discard that
+    qp, np_ = (-q % bq), (-n % bn)
     mask_p = jnp.pad(mask.astype(jnp.int32), ((0, qp), (0, np_)))
-    db_p = jnp.pad(db, ((0, np_), (0, wp)))
-
-    grid = (
-        (q + qp) // bq,
-        (w + wp) // bw,
-        (n + np_) // bn,
-    )
+    wblocks = -(-w // bw)
+    grid = ((q + qp) // bq, wblocks, (n + np_) // bn)
     out = pl.pallas_call(
         _kernel,
         grid=grid,
@@ -83,8 +99,10 @@ def xor_fold(
             pl.BlockSpec((bq, bn), lambda i, j, k: (i, k)),
             pl.BlockSpec((bn, bw), lambda i, j, k: (k, j)),
         ],
-        out_specs=pl.BlockSpec((bq, bw), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((q + qp, w + wp), jnp.uint32),
+        out_specs=pl.BlockSpec((bq, SUBLANES, bw), lambda i, j, k: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct(
+            (q + qp, SUBLANES, wblocks * bw), jnp.uint32
+        ),
         interpret=interpret,
-    )(mask_p, db_p)
-    return out[:q, :w]
+    )(mask_p, db)
+    return fold_sublanes(out)[:q, :w]

@@ -585,7 +585,7 @@ def _pir_serve_fn_bf16(masks, planes):
 
 def _pir_serve_fn_xorbfly(masks, planes):
     """shard_map: local bf16 parity matmul → pack bits → XOR butterfly."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.db import packing
     from repro.dist.sharding import current_mesh, mesh_axis_names
 
@@ -597,7 +597,7 @@ def _pir_serve_fn_xorbfly(masks, planes):
         mesh=mesh,
         in_specs=(P(None, rec_axes), P(rec_axes, None)),
         out_specs=P(None, None),
-        check_rep=False,
+        check_vma=False,
     )
     def _f(m_loc, p_loc):
         acc = jnp.dot(m_loc, p_loc, preferred_element_type=jnp.float32)

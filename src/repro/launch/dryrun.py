@@ -1,6 +1,8 @@
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# the 512 devices are forced CPU devices: never reach for an accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # --- everything above runs before ANY other import (jax locks device count
 # on first init; smoke tests / benches must keep seeing 1 device, so this

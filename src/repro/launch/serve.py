@@ -26,6 +26,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import SCHEMES, make_scheme
 from repro.core.accounting import PrivacyBudget
 from repro.data.pipeline import pir_delta_batch
@@ -154,17 +155,23 @@ def run_sync(args, engine: ServingPipeline) -> None:
             ingest_step += 1
         nq = min(args.batch, args.queries - served)
         idx = rng.integers(0, args.n, size=nq)
+        # each client's last accepted query: flush returns its answer
+        last = {}
         for i, q in enumerate(idx):
-            if not engine.submit(f"client-{i % 32}", int(q)):
+            client = f"client-{i % 32}"
+            if not engine.submit(client, int(q)):
                 print("budget refused a query; stopping")
                 served = args.queries
                 break
+            last[client] = int(q)
         t0 = time.perf_counter()
         out = engine.flush()
         dt = time.perf_counter() - t0
-        # verify a sample
-        q0 = int(idx[0])
-        assert (out[f"client-0"] == engine.store.record_bytes(q0)).all() or True
+        # verify every client's answer (deltas here only append, so old
+        # rows never move)
+        for client, answer in out.items():
+            expect = engine.store.record_bytes(last[client])
+            assert (answer == expect).all(), (client, last[client])
         served += nq
         print(f"batch of {nq:4d} served in {dt*1e3:7.1f} ms "
               f"({nq/dt:8.0f} qps)")
@@ -241,6 +248,7 @@ def run_async(args, engine: ServingPipeline) -> None:
 
 def main() -> None:
     args = build_args().parse_args()
+    use_compile_cache()
     engine = make_engine(args)
     scheme = engine.scheme
 

@@ -23,7 +23,7 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import GNNConfig
@@ -86,7 +86,7 @@ def _aggregate(h, src, dst, w, n_nodes, mean_deg=None):
             P(node_axes) if mean_deg is not None else P(),
         ),
         out_specs=P(node_axes, None),
-        check_rep=False,
+        check_vma=False,
     )
     def _agg(h_loc, src_loc, dst_loc, w_loc, md_loc):
         h_full = jax.lax.all_gather(h_loc, node_axes, axis=0, tiled=True)

@@ -21,7 +21,7 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import current_mesh, mesh_axis_names
@@ -167,7 +167,7 @@ def moe_apply(
         mesh=mesh,
         in_specs=(tok_spec, P(None, None), ew_spec, ew_spec, ew_spec),
         out_specs=(tok_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     def _blk(xt, rw, wg, wi, wo):
         lin = jnp.int32(0)

@@ -65,14 +65,23 @@ front of it (DESIGN.md §Async front):
   so plan cells served cold from the analytic prior acquire their
   measured winner without a request thread ever microbenchmarking.
   Ingest comes first in the idle sequence: freshness is client-visible,
-  banked randomness is not.
+  banked randomness is not. An idle job that raises never takes the
+  flush worker down: the error lands in :attr:`AsyncFrontend.idle_error`
+  and the ``idle_failed`` counter, only that kind of job stops running,
+  batches keep being served (so no future hangs), and :meth:`close`
+  re-raises it. A failed *ingest* is never hidden: the delta it held
+  was not applied, so every later :meth:`ingest` and :meth:`drain`
+  raises instead of acknowledging writes that will not become readable.
 * **Graceful drain**: :meth:`drain` forces the backlog through (partial
   batches included) and blocks until every accepted future is resolved;
   ``close(drain=True)`` (also the context-manager exit) drains before
   stopping. ``close(drain=False)`` cancels whatever is still unserved;
   its wait for in-flight block-policy submitters to settle is bounded by
   ``drain_timeout_s`` on the *scheduler's* injected clock, so fake-clock
-  tests control it like every other timeout in the stack.
+  tests control it like every other timeout in the stack. ``close``
+  joins its workers within the caller's ``timeout`` (else
+  ``_JOIN_TIMEOUT_S``) and raises if one is still running, so a hung
+  device call cannot hang ``close`` forever.
 """
 
 from __future__ import annotations
@@ -153,7 +162,12 @@ class AsyncFrontend:
         self._threads: List[threading.Thread] = []
         self._counters = {"accepted": 0, "shed": 0, "served": 0,
                           "failed": 0, "prefilled": 0, "autotuned": 0,
-                          "ingested": 0, "compacted": 0}
+                          "ingested": 0, "compacted": 0, "idle_failed": 0}
+        #: the first exception an idle-slot job raised (None while every
+        #: idle job has succeeded); close() re-raises it
+        self.idle_error: Optional[BaseException] = None
+        #: idle job kind -> the exception that stopped it
+        self._idle_failed: Dict[str, BaseException] = {}
 
     # ----------------------------------------------------------- lifecycle
     def start(self) -> "AsyncFrontend":
@@ -273,6 +287,7 @@ class AsyncFrontend:
         :class:`~repro.db.live.VersionedStore`."""
         if self._closed:
             raise RuntimeError("frontend is closed to new ingests")
+        self._raise_if_ingest_failed()
         if not self._threads:
             self.start()
         self.pipeline.queue_delta(delta)
@@ -282,25 +297,48 @@ class AsyncFrontend:
     # --------------------------------------------------------------- drain
     def drain(self, timeout: Optional[float] = None) -> bool:
         """Force the backlog through (partial batches included) and block
-        until every accepted request has a resolved future. Returns False
-        on timeout. The frontend keeps accepting afterwards."""
+        until every accepted request has a resolved future and every
+        queued delta is applied. Returns False on timeout; raises if a
+        delta failed to apply. The frontend keeps accepting afterwards."""
         with self._cv:
             self._draining += 1
             self._cv.notify_all()
         try:
             with self._cv:
-                return self._cv.wait_for(self._is_idle, timeout)
+                idle = self._cv.wait_for(self._is_idle, timeout)
         finally:
             with self._cv:
                 self._draining -= 1
+        self._raise_if_ingest_failed()
+        return idle
+
+    def _raise_if_ingest_failed(self) -> None:
+        exc = self._idle_failed.get("ingest")
+        if exc is not None:
+            raise RuntimeError(
+                f"a store delta failed to apply; ingest is stopped: {exc!r}"
+            ) from exc
+
+    #: how long close() waits for its workers when the caller gives no
+    #: timeout: an idle-slot autotune step on a full-size store takes
+    #: seconds, a hung device call forever
+    _JOIN_TIMEOUT_S = 60.0
 
     def close(self, drain: bool = True, timeout: Optional[float] = None) -> None:
-        """Stop accepting; optionally drain, then join the workers.
-        Without drain, unserved futures are cancelled."""
+        """Stop accepting; optionally drain, then join the workers, all
+        within ``timeout`` seconds. Without drain, unserved futures are
+        cancelled. Raises if a worker is still running at the deadline
+        or an idle-slot job failed."""
+        join_by = time.monotonic() + (
+            self._JOIN_TIMEOUT_S if timeout is None else timeout
+        )
         with self._cv:
             self._closed = True
         if drain and self._threads:
-            self.drain(timeout)
+            try:
+                self.drain(timeout)
+            except RuntimeError:
+                pass  # a failed ingest: re-raised below as the idle error
         with self._cv:
             self._stop = True
             self._cv.notify_all()
@@ -309,13 +347,15 @@ class AsyncFrontend:
                 self._ingest.put_nowait(_SENTINEL)
             except queue.Full:
                 break
+        # a job in flight (an idle-slot autotune step) finishes first
         for t in self._threads:
-            t.join(timeout=5.0)
+            t.join(timeout=max(0.0, join_by - time.monotonic()))
+        stuck = [t.name for t in self._threads if t.is_alive()]
         self._threads = []
         if self._executor is not None:
             # the flush worker settles its in-flight batch before exiting,
-            # so this never abandons work
-            self._executor.shutdown(wait=True)
+            # so this only abandons work when that worker is stuck
+            self._executor.shutdown(wait=not stuck)
             self._executor = None
         # cancel anything that never got served (drain=False path); rescan
         # until in-flight block-policy submitters have either enqueued
@@ -352,6 +392,15 @@ class AsyncFrontend:
                 from concurrent.futures import CancelledError
 
                 fut.set_exception(CancelledError())
+        if stuck:
+            raise RuntimeError(
+                f"worker threads still running after close(): {stuck}"
+            )
+        if self.idle_error is not None:
+            raise RuntimeError(
+                f"an idle-slot job failed ({', '.join(self._idle_failed)}): "
+                f"{self.idle_error!r}"
+            ) from self.idle_error
 
     # ------------------------------------------------------------- metrics
     @property
@@ -375,7 +424,10 @@ class AsyncFrontend:
             and not len(self.pipeline.scheduler)
             and not self._pending
             and self._resolving == 0
-            and self.pipeline.pending_deltas == 0
+            # a failed ingest applies no more deltas: drain() raises
+            # instead of waiting on them
+            and (self.pipeline.pending_deltas == 0
+                 or "ingest" in self._idle_failed)
         )
 
     # items admitted per lock acquisition: big enough to keep lock/notify
@@ -478,11 +530,11 @@ class AsyncFrontend:
                 timeout = None if cut else self._flush_wait_s()
                 idle = not len(self.pipeline.scheduler) and not self._unadmitted
             if batch:
-                # local ref: a concurrent close() that gave up joining
-                # this thread may shut down and clear self._executor —
-                # the local keeps the dispatch race-free and the except
-                # below turns a post-shutdown submit into a failed batch
-                # instead of a dead flush worker with hung futures
+                # local ref: a concurrent close() may shut down and
+                # clear self._executor — the local keeps the dispatch
+                # race-free and the except below turns a post-shutdown
+                # submit into a failed batch instead of a dead flush
+                # worker with hung futures
                 executor = self._executor
                 if executor is None:
                     self._serve(batch)
@@ -523,7 +575,7 @@ class AsyncFrontend:
             # client-visible, banked randomness is not — and with no
             # batch in flight here, a delta can never land mid-batch.
             if idle and self.pipeline.pending_deltas:
-                if self.pipeline.ingest_step():
+                if self._idle_job("ingest", self.pipeline.ingest_step):
                     with self._cv:
                         self._counters["ingested"] += 1
                         if self.pipeline.pending_deltas == 0:
@@ -537,14 +589,16 @@ class AsyncFrontend:
             # depth, oracle-checked, never blocking a flush (DESIGN.md
             # §13). compact_log_depth=None (default) disables it.
             if idle and self.compact_log_depth is not None:
-                if self.pipeline.compact_step(
-                    min_log_depth=self.compact_log_depth
-                ):
+                if self._idle_job("compact", lambda: (
+                    self.pipeline.compact_step(
+                        min_log_depth=self.compact_log_depth
+                    )
+                )):
                     with self._cv:
                         self._counters["compacted"] += 1
                     continue
             if self.prefill and self.pipeline.cache is not None and idle:
-                if self.pipeline.prefill_cache():
+                if self._idle_job("prefill", self.pipeline.prefill_cache):
                     with self._cv:
                         self._counters["prefilled"] += 1
                     continue
@@ -553,7 +607,7 @@ class AsyncFrontend:
             # measured winner here, never on the serving path (DESIGN.md
             # §Execution backends)
             if self.autotune and idle:
-                if self.pipeline.autotune_step():
+                if self._idle_job("autotune", self.pipeline.autotune_step):
                     with self._cv:
                         self._counters["autotuned"] += 1
                     continue
@@ -564,6 +618,22 @@ class AsyncFrontend:
                     self._cv.wait(timeout)
         if inflight is not None:  # stop requested with a batch in flight
             self._finish(*inflight)
+
+    def _idle_job(self, kind: str, job) -> int:
+        """Run one idle-slot job of ``kind``; an exception is recorded
+        (see :attr:`idle_error`), counted, and stops that kind only."""
+        if kind in self._idle_failed:
+            return 0
+        try:
+            return job()
+        except Exception as exc:
+            with self._cv:
+                if self.idle_error is None:
+                    self.idle_error = exc
+                self._idle_failed[kind] = exc
+                self._counters["idle_failed"] += 1
+                self._cv.notify_all()
+            return 0
 
     def _serve(self, batch: List[Request]) -> None:
         """Single-threaded flush: plan + execute + resolve inline."""

@@ -74,7 +74,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.db import packing
@@ -182,6 +182,9 @@ class ShardedBackend:
         # version's bitplanes after the planner moved on
         self._pinned_planes: Optional[Tuple[int, jnp.ndarray]] = None
         self.path_counts = {"fold": 0, "parity": 0, "sparse": 0, "direct": 0}
+        #: every plan :meth:`answer_batch` executed, by ``describe()`` —
+        #: what an on-chip check reads to prove which kernels ran
+        self.plans_executed: Dict[str, ExecutionPlan] = {}
 
     @property
     def backend_name(self) -> str:
@@ -330,9 +333,12 @@ class ShardedBackend:
                     continue
                 sel = (rows_np >= start) & (rows_np < start + block)
                 local = jnp.asarray(rows_np[sel] - start, jnp.int32)
+                # the rows go to the shard's own device: a store that is
+                # itself sharded hands them over spread across the mesh
                 datas.append(
                     scatter_update(
-                        jnp.asarray(sh.data), local, fresh_rows[sel],
+                        jnp.asarray(sh.data), local,
+                        jax.device_put(fresh_rows[sel], sh.device),
                         backend=self.backend_name, family="scatter_shard",
                     )
                 )
@@ -479,7 +485,7 @@ class ShardedBackend:
             mesh=mesh,
             in_specs=(P(raxes, None), P(qaxes or None, raxes)),
             out_specs=P(qaxes or None, None),
-            check_rep=False,
+            check_vma=False,
         )
         def _answer(operand_loc, m_loc):
             return xor_psum(answer_shard(operand_loc, m_loc), raxes)
@@ -658,4 +664,6 @@ class ShardedBackend:
                 + time.perf_counter() - t0,
             )
             responses.append(r)
+        if plan is not None:
+            self.plans_executed[plan.describe()] = plan
         return jnp.stack(responses)

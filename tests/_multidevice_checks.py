@@ -128,7 +128,7 @@ def check_lm_end_to_end():
 
 def check_compressed_psum():
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.dist.collectives import compressed_psum
 
     x = jax.random.normal(jax.random.key(10), (8, 64))
@@ -416,7 +416,7 @@ def check_pir_touched_shard_ingest():
 def check_xor_psum_and_record_lookup():
     """The GF(2) collectives against their single-device references."""
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.dist.collectives import sharded_record_lookup, xor_psum
 
     x = jax.random.randint(
@@ -424,7 +424,7 @@ def check_xor_psum_and_record_lookup():
     ).astype(jnp.uint32)
     with mesh_rules(MESH, RULES):
         @partial(shard_map, mesh=MESH, in_specs=P(("data", "model"), None),
-                 out_specs=P(("data", "model"), None), check_rep=False)
+                 out_specs=P(("data", "model"), None), check_vma=False)
         def f(xl):
             return xor_psum(xl, ("data", "model"))
 

@@ -20,6 +20,7 @@ from repro.kernels.backend import (
     AutotuneTable,
     ExecutionPlan,
     KernelPlanner,
+    TuneCell,
     device_fingerprint,
     get_backend,
     register_backend,
@@ -511,3 +512,31 @@ def test_prepared_plan_is_used_by_answer_batch():
         for p in range(len(routed.servers))
     ])
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_candidates_that_cannot_fit_the_device_stay_out_of_the_race():
+    """With the device's memory limit known, the parity path (whose
+    bitplanes take 6 bytes per record bit) and the sparse oracle (which
+    gathers [bucket, m, W] rows) leave the race once they cannot fit
+    beside the store — and a cold dense cell's prior stays on fold even
+    past the parity crossover."""
+    store = make_synthetic_store(256, 16, seed=2)
+    planner = KernelPlanner(store, backend="auto", table=AutotuneTable())
+    dense = TuneCell(scheme="chor", bucket=256, impl="pallas", theta=None,
+                     n_eff=256, m_budget=None)
+    sparse = TuneCell(scheme="sparse", bucket=1024, impl="pallas",
+                      theta=0.25, n_eff=256, m_budget=96)
+    labels = lambda cell: [c.label for c in planner._candidates(cell)]
+
+    assert "parity/pallas" in labels(dense)
+    assert "sparse_ref/ref" in labels(sparse)
+    assert planner._prior(dense)[0] == "parity"
+
+    planner = KernelPlanner(
+        store, backend="auto", table=AutotuneTable(),
+        memory_limit_bytes=store.nbytes + 256 * 128 * 6 - 1,
+    )
+    assert labels(dense) == ["fold/pallas", "fold/ref"]
+    assert planner._prior(dense)[0] == "fold"
+    assert labels(sparse)
+    assert all("/pallas" in label for label in labels(sparse))

@@ -227,14 +227,17 @@ def test_fused_block_w_vmem_gate():
     # fits: tiny store keeps the full default block
     assert fused_block_w(256, 16) == 16
     assert fused_block_w(4096, 512) == 128  # capped at the default block
-    # shrinks to fit: 64k records × 128 words × 4 B = 32 MiB > budget
-    assert 0 < fused_block_w(65536, 128) < 128
+    # shrinks to fit: W = 384 words is too wide, 128 lanes fit
+    assert fused_block_w(16384, 384, block_w=512) == 128
+    # never below one 128-lane vreg: 64k × 128 words × 4 B = 32 MiB > budget
+    assert fused_block_w(65536, 128) == 0
     # nothing fits at CT scale on one host -> 0 = fall back to the pair
     assert fused_block_w(10**6, 384) == 0
-    # non-pow2 W rounds DOWN to a power of two before shrinking, and the
-    # min(8, W) floor holds: no lane-starved sliver blocks ever escape
-    assert fused_block_w(200_000, 12) == 8   # 8-word slab (6.4 MB) fits
-    assert fused_block_w(300_000, 12) == 0   # 8-word slab doesn't -> pair
+    # W under 128 lanes is only legal whole: no sliver blocks ever escape
+    assert fused_block_w(150_000, 12) == 12  # whole 12-word slab (7.2 MB)
+    assert fused_block_w(200_000, 12) == 0   # 9.6 MB doesn't fit -> pair
+    # on a TPU the budget is a quarter of the VMEM the table lists
+    assert fused_block_w(65536, 128, budget_bytes=32 << 20) == 128
 
 
 def test_sparse_index_budget_bounds():
@@ -463,3 +466,38 @@ def test_multi_vmem_gate_falls_back_to_pair():
     )
     assert plan2.path == "sparse_multi_fused", plan2.path
     assert dict(plan2.blocks)["k_max"] == 4
+
+
+def test_fused_vmem_budget_names_the_tpu_kind(monkeypatch):
+    """v5e's VMEM is in the table (a quarter of 128 MiB for the slab); a
+    TPU kind the table does not list is an error, never a default."""
+    from repro.kernels import fused
+
+    class Dev:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(fused.jax, "devices", lambda: [Dev()])
+    assert fused.fused_vmem_budget() == 32 * 1024 * 1024
+    Dev.device_kind = "TPU v99"
+    with pytest.raises(ValueError, match="TPU v99"):
+        fused.fused_vmem_budget()
+
+
+@pytest.mark.parametrize("q", [1, 5])
+def test_index_chunks_match_one_call(monkeypatch, q):
+    """An index list longer than scalar memory holds runs as chunks whose
+    answers XOR together — bit-identical to the oracle, -1 padding and
+    ragged chunk edges included, for every kernel that takes one."""
+    from repro.kernels import tiles
+
+    store, mask = _case(300, 20, q, seed=4)
+    idx = indices_from_mask(mask, 136)
+    want = np.asarray(ref.gather_xor_ref(store.packed, idx))
+    # 64 bytes of indices per call: every call is split into many chunks
+    monkeypatch.setattr(tiles, "SMEM_INDEX_BYTES", 64)
+    got = gather_xor(store.packed, idx, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(
+        np.asarray(fused_gather_fold(store.packed, idx, interpret=True)), want
+    )

@@ -466,3 +466,120 @@ def test_idle_slot_runs_autotune_step_and_counts():
         entry["source"] == "measured"
         for _, entry in pipe.backend.planner.table.items()
     )
+
+
+def test_idle_slot_error_is_recorded_and_raised_on_close(monkeypatch):
+    """An idle-slot job that raises (here: an autotune candidate the
+    compiler refuses) must not take the flush worker down: later
+    submits still resolve, the error and the counter say what failed,
+    and close() re-raises it instead of exiting cleanly."""
+    pipe = make_pipe(n=128, max_batch=8)
+
+    def refused(*_a, **_kw):
+        raise ValueError("candidate refused")
+
+    monkeypatch.setattr(pipe, "autotune_step", refused)
+    fe = AsyncFrontend(pipe, idle_tick_s=0.001).start()
+    deadline = time.monotonic() + 30.0
+    while fe.idle_error is None and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert isinstance(fe.idle_error, ValueError)
+    assert fe.metrics["idle_failed"] == 1
+    fut = fe.submit("a", 17)
+    assert fe.drain(timeout=30.0)
+    np.testing.assert_array_equal(
+        fut.result(timeout=5.0), pipe.store.record_bytes(17)
+    )
+    with pytest.raises(RuntimeError, match="idle-slot job failed"):
+        fe.close(drain=True)
+
+
+def _live_pipe(n=128):
+    from repro.db import VersionedStore
+
+    live = VersionedStore(make_synthetic_store(n, 16, seed=9), backend="ref")
+    return ServingPipeline(live, make_scheme("chor", d=2, d_a=1)), live
+
+
+def _wait_for_idle_error(fe, timeout_s=30.0):
+    deadline = time.monotonic() + timeout_s
+    while fe.idle_error is None and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert fe.idle_error is not None
+
+
+def test_failed_autotune_step_keeps_ingest_running(monkeypatch):
+    """A failing performance job stops only itself: a delta ingested
+    after an autotune step raised is still applied, drain() waits for
+    it, and the next read serves the new row."""
+    from repro.db import Delta
+
+    pipe, live = _live_pipe()
+
+    def refused(*_a, **_kw):
+        raise ValueError("candidate refused")
+
+    monkeypatch.setattr(pipe, "autotune_step", refused)
+    fe = AsyncFrontend(pipe, idle_tick_s=0.001).start()
+    _wait_for_idle_error(fe)
+    row = np.arange(16, dtype=np.uint8)[None, :] + 100
+    fe.ingest(Delta.update([5], row))
+    assert fe.drain(timeout=30.0)
+    assert pipe.pending_deltas == 0 and fe.metrics["ingested"] == 1
+    fut = fe.submit("a", 5)
+    assert fe.drain(timeout=30.0)
+    np.testing.assert_array_equal(fut.result(timeout=5.0), row[0])
+    assert fe.metrics["idle_failed"] == 1
+    with pytest.raises(RuntimeError, match="autotune"):
+        fe.close(drain=True)
+
+
+def test_failed_ingest_is_raised_not_acknowledged(monkeypatch):
+    """A delta that fails to apply is never reported as drained: drain()
+    and every later ingest() raise, while reads keep being served."""
+    from repro.db import Delta
+
+    pipe, live = _live_pipe()
+
+    def broken(*_a, **_kw):
+        raise ValueError("delta rejected")
+
+    monkeypatch.setattr(pipe, "ingest", broken)
+    fe = AsyncFrontend(pipe, idle_tick_s=0.001, autotune=False).start()
+    fe.ingest(Delta.update([5], np.zeros((1, 16), np.uint8)))
+    _wait_for_idle_error(fe)
+    with pytest.raises(RuntimeError, match="delta failed to apply"):
+        fe.drain(timeout=30.0)
+    with pytest.raises(RuntimeError, match="ingest is stopped"):
+        fe.ingest(Delta.update([6], np.zeros((1, 16), np.uint8)))
+    fut = fe.submit("a", 5)
+    with pytest.raises(RuntimeError, match="delta failed to apply"):
+        fe.drain(timeout=30.0)
+    np.testing.assert_array_equal(
+        fut.result(timeout=5.0), live.snapshot().record_bytes(5)
+    )
+    with pytest.raises(RuntimeError, match="ingest"):
+        fe.close(drain=True)
+
+
+def test_close_reports_a_stuck_worker_within_its_timeout(monkeypatch):
+    """A worker stuck in a job (a hung device call) makes close() raise
+    at its timeout instead of waiting forever."""
+    pipe = make_pipe(n=128, max_batch=8)
+    entered, release = threading.Event(), threading.Event()
+
+    def hung(*_a, **_kw):
+        entered.set()
+        release.wait(30.0)
+        return 0
+
+    monkeypatch.setattr(pipe, "autotune_step", hung)
+    fe = AsyncFrontend(pipe, idle_tick_s=0.001).start()
+    assert entered.wait(30.0)
+    t0 = time.monotonic()
+    try:
+        with pytest.raises(RuntimeError, match="still running"):
+            fe.close(timeout=0.5)
+        assert time.monotonic() - t0 < 10.0
+    finally:
+        release.set()
