@@ -36,6 +36,7 @@ the pre-refactor one-file engine's constructor and methods, unchanged.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -63,6 +64,7 @@ from repro.serve.cache import QueryCache, block_pre_ready, scheme_signature
 from repro.serve.router import SchemeRouter
 from repro.serve.scheduler import BatchScheduler, Request
 from repro.serve.sharded import ServerStats, ShardedBackend
+from repro.serve.trace import span
 
 __all__ = ["ServerStats", "PlannedBatch", "ServingPipeline", "PIRServingEngine"]
 
@@ -95,6 +97,10 @@ class PlannedBatch:
     # can never tear across an ingest.
     store: Optional[RecordStore] = None
     store_version: int = 0
+    # the pipeline's id of this batch (the ``batch`` stat of its plan and
+    # execute spans) and the scheduler-clock time its planning began
+    batch_id: int = 0
+    t_plan: float = 0.0
 
 
 class ServingPipeline:
@@ -160,6 +166,7 @@ class ServingPipeline:
         # device work in execute runs outside the lock; the sync path
         # takes it uncontended.
         self._phase_lock = threading.Lock()
+        self._batch_ids = itertools.count()
         # the per-query (ε, δ) price is constant between remeshes (fixed
         # scheme, fixed n): compute once so admission is O(1) float math;
         # degrade_replicas re-prices it when survivors shrink the scheme
@@ -174,10 +181,15 @@ class ServingPipeline:
         self._serviceable = True
         self.last_remesh: Optional[RemeshPlan] = None
         self.degraded: Optional[Dict[str, float]] = None
+        # queue_wait_s, dispatch_wait_s and execute_s are summed over
+        # requests, on the scheduler's clock: enqueue -> cut (take_batch),
+        # start of planning -> start of execute, execute's start -> its
+        # results ready; dequeued counts the requests cut
         self.metrics = {
-            "queries": 0, "batches": 0, "records_touched": 0.0,
-            "blocks_sent": 0.0, "refused": 0, "padded": 0, "truncated": 0,
-            "cache_hits": 0, "remeshes": 0,
+            "queries": 0, "batches": 0, "refused": 0, "padded": 0,
+            "truncated": 0, "cache_hits": 0, "remeshes": 0,
+            "queue_wait_s": 0.0, "dequeued": 0,
+            "dispatch_wait_s": 0.0, "execute_s": 0.0,
             "d_effective": float(self.staged.d),
             "epsilon_per_query": self._eps_per_query,
             "delta_per_query": self._delta_per_query,
@@ -376,8 +388,19 @@ class ServingPipeline:
         """
         if not batch:
             return None
-        if any(r.indices for r in batch):
-            return self._plan_requests_multi(batch)
+        t_plan = self.scheduler.clock()
+        batch_id = next(self._batch_ids)
+        with span("plan", batch=batch_id, requests=len(batch)) as sp:
+            if any(r.indices for r in batch):
+                planned = self._plan_requests_multi(batch)
+            else:
+                planned = self._plan_requests_single(batch)
+            sp.set_metadata(misses=len(planned.misses), bucket=planned.padded)
+        planned.batch_id, planned.t_plan = batch_id, t_plan
+        return planned
+
+    def _plan_requests_single(self, batch: List[Request]) -> PlannedBatch:
+        """The single-index half of :meth:`plan_requests`."""
         results: List[Optional[Tuple[Request, np.ndarray]]] = [None] * len(batch)
         with self._phase_lock:
             # pin the batch's snapshot under the lock: everything below —
@@ -421,7 +444,8 @@ class ServingPipeline:
                     self.cache.take_pre(padded)
                     if self.cache is not None else None
                 )
-            routed = self.router.plan(sub, store.n, q_idx, pre=pre)
+            with span("query_gen", bucket=padded):
+                routed = self.router.plan(sub, store.n, q_idx, pre=pre)
             if self.live is not None:
                 routed.store_version = ver
             exec_plan = self.backend.prepare(routed, scheme=self.staged)
@@ -442,9 +466,7 @@ class ServingPipeline:
             return np.stack([np.asarray(a) for a in rows])
         return np.asarray(rows[0])
 
-    def _plan_requests_multi(
-        self, batch: List[Request]
-    ) -> Optional[PlannedBatch]:
+    def _plan_requests_multi(self, batch: List[Request]) -> PlannedBatch:
         """The multi-index half of :meth:`plan_requests` (DESIGN.md
         §Multi-index wire format): cache hits resolve *per (client,
         index)* — a request whose indices all hit never touches a wire,
@@ -497,9 +519,10 @@ class ServingPipeline:
                     self.cache.take_pre(padded)
                     if self.cache is not None else None
                 )
-            routed = self.router.plan_many(
-                sub, store.n, miss_lists, pre=pre
-            )
+            with span("query_gen", bucket=padded):
+                routed = self.router.plan_many(
+                    sub, store.n, miss_lists, pre=pre
+                )
             if self.live is not None:
                 routed.queries.store_version = ver  # flat wire carries it
             exec_plan = self.backend.prepare(routed, scheme=self.staged)
@@ -513,7 +536,7 @@ class ServingPipeline:
         )
 
     def _execute_planned_multi(
-        self, planned: PlannedBatch
+        self, planned: PlannedBatch, t1: float
     ) -> List[Tuple[Request, np.ndarray]]:
         """Execute a multi-index planned batch: one backend answer for
         the whole flattened wire batch, ONE flat reconstruction + one
@@ -526,74 +549,127 @@ class ServingPipeline:
         level API; the serving path inlines it to keep the hot path at
         one transfer per batch."""
         results = planned.results
-        if planned.routed is not None:
-            misses = planned.misses
-            routed = planned.routed
-            pinned = planned.store if planned.store is not None else self.store
-            clock = self.scheduler.clock
-            t1 = clock()
-            responses = self.backend.answer_batch(
-                routed, plan=planned.exec_plan, scheme=self.staged,
-                store=planned.store,
-            )
-            # reconstruct the whole padded [B, W] batch in one shot —
-            # MultiQueries delegates its wire view, so the scheme's flat
-            # reconstruct applies; padding rows are sliced away below
-            flat_out = self.router.finalize(routed, responses)
-            flat_out.block_until_ready()
-            dt = planned.plan_s + (clock() - t1)
+        if planned.routed is None:
+            return results  # type: ignore[return-value]
+        misses = planned.misses
+        routed = planned.routed
+        pinned = planned.store if planned.store is not None else self.store
+        responses = self.backend.answer_batch(
+            routed, plan=planned.exec_plan, scheme=self.staged,
+            store=planned.store,
+        )
+        with span("finalize", batch=planned.batch_id):
+            with span("reconstruct"):
+                # reconstruct the whole padded [B, W] batch in one shot —
+                # MultiQueries delegates its wire view, so the scheme's
+                # flat reconstruct applies; padding rows are sliced away
+                flat_out = self.router.finalize(routed, responses)
+                flat_out.block_until_ready()
+            dt = planned.plan_s + (self.scheduler.clock() - t1)
 
-            nbytes = -(-pinned.record_bits // 8)
-            raw_all = packing.unpack_bytes_np(np.asarray(flat_out), nbytes)
-            k_max = routed.k_max
-            raw = np.concatenate([
-                raw_all[j * k_max: j * k_max + len(lst)]
-                for j, lst in enumerate(planned.miss_lists)
-            ]) if planned.miss_lists else raw_all[:0]
+            with span("unpack"):
+                nbytes = -(-pinned.record_bits // 8)
+                raw_all = packing.unpack_bytes_np(np.asarray(flat_out), nbytes)
+                k_max = routed.k_max
+                raw = np.concatenate([
+                    raw_all[j * k_max: j * k_max + len(lst)]
+                    for j, lst in enumerate(planned.miss_lists)
+                ]) if planned.miss_lists else raw_all[:0]
             flat_total = sum(len(lst) for lst in planned.miss_lists)
-            cols = None
-            if self.cache is not None:
-                col_bytes = (
-                    routed.payload.nbytes // routed.payload.shape[1]
-                )
-                if col_bytes <= self.cache.max_query_vector_bytes:
-                    cols = np.asarray(routed.payload)
+            with span("cache_insert"):
+                cols = None
+                if self.cache is not None:
+                    col_bytes = (
+                        routed.payload.nbytes // routed.payload.shape[1]
+                    )
+                    if col_bytes <= self.cache.max_query_vector_bytes:
+                        cols = np.asarray(routed.payload)
 
-            with self._phase_lock:
-                self.scheduler.observe_service(planned.padded, dt)
-                self.metrics["batches"] += 1
-                self.metrics["padded"] += planned.padded - flat_total
-                costs = self.staged.costs(pinned.n)
-                self.metrics["records_touched"] += (
-                    costs["C_p"] / 2.0 * flat_total
-                )
-                self.metrics["blocks_sent"] += costs["C_m"] * flat_total
-                start = 0
-                for j, r in enumerate(misses):
-                    fresh = raw[start:start + len(planned.miss_lists[j])]
-                    start += len(planned.miss_lists[j])
-                    rows = list(planned.partial[j])
-                    f = 0
-                    for pos in range(len(rows)):
-                        if rows[pos] is not None:
-                            continue
-                        answer = np.array(fresh[f])
-                        rows[pos] = answer
+                with self._phase_lock:
+                    self.scheduler.observe_service(planned.padded, dt)
+                    self.metrics["batches"] += 1
+                    self.metrics["padded"] += planned.padded - flat_total
+                    start = 0
+                    for j, r in enumerate(misses):
+                        fresh = raw[start:start + len(planned.miss_lists[j])]
+                        start += len(planned.miss_lists[j])
+                        rows = list(planned.partial[j])
+                        f = 0
+                        for pos in range(len(rows)):
+                            if rows[pos] is not None:
+                                continue
+                            answer = np.array(fresh[f])
+                            rows[pos] = answer
+                            if self.cache is not None:
+                                # request j's f-th wire index sits at flat
+                                # column j·k_max + f (the padded layout)
+                                flat_col = j * routed.k_max + f
+                                self.cache.insert(
+                                    r.client, planned.miss_lists[j][f],
+                                    answer=answer,
+                                    query_cols=(
+                                        None if cols is None
+                                        else cols[:, flat_col]
+                                    ),
+                                    version=planned.store_version,
+                                )
+                            f += 1
+                        results[planned.miss_pos[j]] = (
+                            r, self._assemble(r, rows)
+                        )
+        return results  # type: ignore[return-value]
+
+    def _execute_planned_single(
+        self, planned: PlannedBatch, t1: float
+    ) -> List[Tuple[Request, np.ndarray]]:
+        """The single-index half of :meth:`execute_planned`."""
+        results = planned.results
+        if planned.routed is None:
+            return results  # type: ignore[return-value]
+        misses, miss_pos = planned.misses, planned.miss_pos
+        b = len(misses)
+        routed = planned.routed
+        pinned = planned.store if planned.store is not None else self.store
+        responses = self.backend.answer_batch(
+            routed, plan=planned.exec_plan, scheme=self.staged,
+            store=planned.store,
+        )
+        with span("finalize", batch=planned.batch_id):
+            with span("reconstruct"):
+                out = self.router.finalize(routed, responses)
+                out.block_until_ready()
+            dt = planned.plan_s + (self.scheduler.clock() - t1)
+
+            with span("unpack"):
+                nbytes = -(-pinned.record_bits // 8)
+                raw = packing.unpack_bytes_np(np.asarray(out[:b]), nbytes)
+            with span("cache_insert"):
+                cols = None
+                if self.cache is not None:
+                    # one device->host transfer for the whole payload,
+                    # skipped when a single column would blow the cache's
+                    # byte cap
+                    col_bytes = (
+                        routed.payload.nbytes // routed.payload.shape[1]
+                    )
+                    if col_bytes <= self.cache.max_query_vector_bytes:
+                        cols = np.asarray(routed.payload[:, :b])
+
+                with self._phase_lock:
+                    self.scheduler.observe_service(planned.padded, dt)
+                    self.metrics["batches"] += 1
+                    self.metrics["padded"] += planned.padded - b
+                    for j, r in enumerate(misses):
+                        answer = np.array(raw[j])
+                        results[miss_pos[j]] = (r, answer)
                         if self.cache is not None:
-                            # request j's f-th wire index sits at flat
-                            # column j·k_max + f (the padded layout)
-                            flat_col = j * routed.k_max + f
                             self.cache.insert(
-                                r.client, planned.miss_lists[j][f],
-                                answer=answer,
+                                r.client, r.index, answer=answer,
                                 query_cols=(
-                                    None if cols is None
-                                    else cols[:, flat_col]
+                                    None if cols is None else cols[:, j]
                                 ),
                                 version=planned.store_version,
                             )
-                        f += 1
-                    results[planned.miss_pos[j]] = (r, self._assemble(r, rows))
         return results  # type: ignore[return-value]
 
     def execute_planned(
@@ -605,60 +681,26 @@ class ServingPipeline:
         concurrent :meth:`plan_requests` never waits on it."""
         if planned is None:
             return []
-        if planned.miss_lists is not None:  # a jagged multi-index batch
-            return self._execute_planned_multi(planned)
-        results = planned.results
-        if planned.routed is not None:
-            misses, miss_pos = planned.misses, planned.miss_pos
-            b = len(misses)
-            routed = planned.routed
-            pinned = planned.store if planned.store is not None else self.store
-            # service time = this batch's own plan + execute wall time;
-            # timing from execute's start (not the plan's t0) keeps the
-            # scheduler's EMA honest when the double buffer queues this
-            # execute behind the previous batch's — queue wait is not
-            # per-batch cost and would otherwise shrink the target.
-            # Both phases read the scheduler's own clock so fake-clock
-            # tests can pin exactly what the EMA is fed.
-            clock = self.scheduler.clock
-            t1 = clock()
-            responses = self.backend.answer_batch(
-                routed, plan=planned.exec_plan, scheme=self.staged,
-                store=planned.store,
-            )
-            out = self.router.finalize(routed, responses)
-            out.block_until_ready()
-            dt = planned.plan_s + (clock() - t1)
-
-            nbytes = -(-pinned.record_bits // 8)
-            raw = packing.unpack_bytes_np(np.asarray(out[:b]), nbytes)
-            cols = None
-            if self.cache is not None:
-                # one device->host transfer for the whole payload, skipped
-                # when a single column would blow the cache's byte cap
-                col_bytes = (
-                    routed.payload.nbytes // routed.payload.shape[1]
-                )
-                if col_bytes <= self.cache.max_query_vector_bytes:
-                    cols = np.asarray(routed.payload[:, :b])
-
-            with self._phase_lock:
-                self.scheduler.observe_service(planned.padded, dt)
-                self.metrics["batches"] += 1
-                self.metrics["padded"] += planned.padded - b
-                costs = self.staged.costs(pinned.n)
-                self.metrics["records_touched"] += costs["C_p"] / 2.0 * b
-                self.metrics["blocks_sent"] += costs["C_m"] * b
-                for j, r in enumerate(misses):
-                    answer = np.array(raw[j])
-                    results[miss_pos[j]] = (r, answer)
-                    if self.cache is not None:
-                        self.cache.insert(
-                            r.client, r.index, answer=answer,
-                            query_cols=None if cols is None else cols[:, j],
-                            version=planned.store_version,
-                        )
-        return results  # type: ignore[return-value]
+        # service time = this batch's own plan + execute wall time;
+        # timing from execute's start (not the plan's t0) keeps the
+        # scheduler's EMA honest when the double buffer queues this
+        # execute behind the previous batch's — queue wait is not
+        # per-batch cost and would otherwise shrink the target. Both
+        # phases read the scheduler's own clock so fake-clock tests can
+        # pin exactly what the EMA and the wait counters are fed.
+        clock = self.scheduler.clock
+        t_exec = clock()
+        with span("execute", batch=planned.batch_id):
+            if planned.miss_lists is not None:  # a jagged multi-index batch
+                results = self._execute_planned_multi(planned, t_exec)
+            else:
+                results = self._execute_planned_single(planned, t_exec)
+        t_done = clock()
+        n = len(planned.batch)
+        with self._phase_lock:
+            self.metrics["dispatch_wait_s"] += (t_exec - planned.t_plan) * n
+            self.metrics["execute_s"] += (t_done - t_exec) * n
+        return results
 
     def serve_requests(
         self, batch: List[Request]
@@ -678,9 +720,12 @@ class ServingPipeline:
         leaves the rest queued)."""
         if not len(self.scheduler):
             return []
+        now = self.scheduler.clock()
         batch = self.scheduler.next_batch()
         if len(self.scheduler):
             self.metrics["truncated"] += 1
+        self.metrics["queue_wait_s"] += sum(now - r.t_enqueue for r in batch)
+        self.metrics["dequeued"] += len(batch)
         return batch
 
     def prefill_cache(self, bucket: Optional[int] = None) -> int:

@@ -96,6 +96,7 @@ import numpy as np
 
 from repro.serve.engine import PlannedBatch, ServingPipeline
 from repro.serve.scheduler import Request
+from repro.serve.trace import span
 
 __all__ = ["BackpressureError", "AsyncFrontend"]
 
@@ -156,13 +157,17 @@ class AsyncFrontend:
         self._pending: Dict[int, Future] = {}   # Request.seq -> future
         self._unadmitted = 0                    # queued but not yet admitted
         self._resolving = 0                     # popped but not yet resolved
+        self._ingesting = False                 # a delta popped, not yet applied
         self._draining = 0
         self._closed = False
         self._stop = False
         self._threads: List[threading.Thread] = []
+        # admit_wait_s: submit -> admission (or refusal), summed over the
+        # admit_count requests admission saw, on the scheduler's clock
         self._counters = {"accepted": 0, "shed": 0, "served": 0,
                           "failed": 0, "prefilled": 0, "autotuned": 0,
-                          "ingested": 0, "compacted": 0, "idle_failed": 0}
+                          "ingested": 0, "compacted": 0, "idle_failed": 0,
+                          "admit_wait_s": 0.0, "admit_count": 0}
         #: the first exception an idle-slot job raised (None while every
         #: idle job has succeeded); close() re-raises it
         self.idle_error: Optional[BaseException] = None
@@ -228,7 +233,7 @@ class AsyncFrontend:
         if not self._threads:
             self.start()
         fut: "Future[np.ndarray]" = Future()
-        item = (client, index, fut)
+        item = (client, index, fut, self.pipeline.scheduler.clock())
         with self._cv:
             self._unadmitted += 1
             self._counters["accepted"] += 1
@@ -424,6 +429,7 @@ class AsyncFrontend:
             and not len(self.pipeline.scheduler)
             and not self._pending
             and self._resolving == 0
+            and not self._ingesting
             # a failed ingest applies no more deltas: drain() raises
             # instead of waiting on them
             and (self.pipeline.pending_deltas == 0
@@ -459,10 +465,13 @@ class AsyncFrontend:
                     break
                 items.append(nxt)
             refusals: List[Future] = []
-            with self._cv:
+            with self._cv, span("admit", items=len(items)):
                 self._unadmitted -= len(items)
-                for client, index, fut in items:
+                now = self.pipeline.scheduler.clock()
+                for client, index, fut, t_submit in items:
                     if fut.set_running_or_notify_cancel():
+                        self._counters["admit_wait_s"] += now - t_submit
+                        self._counters["admit_count"] += 1
                         req = (
                             self.pipeline.submit_request_many(client, index)
                             if isinstance(index, tuple)
@@ -520,7 +529,7 @@ class AsyncFrontend:
     def _flush_loop(self) -> None:
         # double-buffer state: the one batch whose execute stage is in
         # flight on the executor thread, with its original requests
-        inflight: Optional[Tuple[List[Request], Future]] = None
+        inflight: Optional[Tuple[List[Request], Future, int]] = None
         while True:
             with self._cv:
                 if self._stop:
@@ -557,6 +566,7 @@ class AsyncFrontend:
                         executor.submit(
                             self.pipeline.execute_planned, planned
                         ),
+                        planned.batch_id,
                     )
                 except RuntimeError as exc:  # executor already shut down
                     self._fail(batch, exc)
@@ -575,12 +585,19 @@ class AsyncFrontend:
             # client-visible, banked randomness is not — and with no
             # batch in flight here, a delta can never land mid-batch.
             if idle and self.pipeline.pending_deltas:
-                if self._idle_job("ingest", self.pipeline.ingest_step):
-                    with self._cv:
+                # ingest_step pops the delta before applying it: drain()
+                # must not see the backlog empty until it has landed
+                with self._cv:
+                    self._ingesting = True
+                applied = self._idle_job("ingest", self.pipeline.ingest_step)
+                with self._cv:
+                    self._ingesting = False
+                    if applied:
                         self._counters["ingested"] += 1
-                        if self.pipeline.pending_deltas == 0:
-                            # drain() also waits on the delta backlog
-                            self._cv.notify_all()
+                    if self.pipeline.pending_deltas == 0:
+                        # drain() also waits on the delta backlog
+                        self._cv.notify_all()
+                if applied:
                     continue
             # delta-log compaction rides the same idle machinery, right
             # after ingest (a just-applied burst is exactly when the log
@@ -615,7 +632,9 @@ class AsyncFrontend:
                 if self._stop:
                     break
                 if not self._should_cut():
-                    self._cv.wait(timeout)
+                    with span("wait.arrivals",
+                              queued=len(self.pipeline.scheduler)):
+                        self._cv.wait(timeout)
         if inflight is not None:  # stop requested with a batch in flight
             self._finish(*inflight)
 
@@ -625,7 +644,8 @@ class AsyncFrontend:
         if kind in self._idle_failed:
             return 0
         try:
-            return job()
+            with span("idle." + kind):
+                return job()
         except Exception as exc:
             with self._cv:
                 if self.idle_error is None:
@@ -644,11 +664,15 @@ class AsyncFrontend:
             return
         self._resolve(results)
 
-    def _finish(self, batch: List[Request], fut: Future) -> None:
-        """Settle one double-buffered batch: wait for its execute stage
-        and resolve (or fail) its futures."""
+    def _finish(
+        self, batch: List[Request], fut: Future, batch_id: int
+    ) -> None:
+        """Settle one double-buffered batch (``batch_id``, the pipeline's
+        id of it): wait for its execute stage and resolve (or fail) its
+        futures."""
         try:
-            results = fut.result()
+            with span("wait.inflight", batch=batch_id):
+                results = fut.result()
         except Exception as exc:
             self._fail(batch, exc)
             return

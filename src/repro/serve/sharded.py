@@ -95,6 +95,7 @@ from repro.kernels.backend import (
     shard_answer_fn,
 )
 from repro.core.protocol import MultiQueries, Queries
+from repro.serve.trace import span
 
 __all__ = ["ServerStats", "ShardedBackend"]
 
@@ -648,22 +649,29 @@ class ShardedBackend:
         Returns stacked responses: [d_eff, B, W] (mask) or
         [d_eff, B, k, W] (index), ordered like ``routed.servers``.
         """
+        pinned = store if store is not None else self.store
         responses = []
-        for pos, sid in enumerate(routed.servers):
-            t0 = time.perf_counter()
-            if routed.kind == "mask":
-                r, plan = self._answer_mask_server(
-                    routed.payload[pos], routed, plan, scheme, store
+        with span("answer", servers=len(routed.servers),
+                  bucket=int(routed.payload.shape[1]), n=int(pinned.n),
+                  words=int(pinned.words), kind=routed.kind):
+            for pos, sid in enumerate(routed.servers):
+                t0 = time.perf_counter()
+                with span("answer.server", server=int(sid)):
+                    if routed.kind == "mask":
+                        r, plan = self._answer_mask_server(
+                            routed.payload[pos], routed, plan, scheme, store
+                        )
+                    else:
+                        r = self._answer_index_server(
+                            routed.payload[pos], store
+                        )
+                r.block_until_ready()
+                self.observe_latency(
+                    sid,
+                    (self._sim(sid) if self._sim else 0.0)
+                    + time.perf_counter() - t0,
                 )
-            else:
-                r = self._answer_index_server(routed.payload[pos], store)
-            r.block_until_ready()
-            self.observe_latency(
-                sid,
-                (self._sim(sid) if self._sim else 0.0)
-                + time.perf_counter() - t0,
-            )
-            responses.append(r)
-        if plan is not None:
-            self.plans_executed[plan.describe()] = plan
-        return jnp.stack(responses)
+                responses.append(r)
+            if plan is not None:
+                self.plans_executed[plan.describe()] = plan
+            return jnp.stack(responses)

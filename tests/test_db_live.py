@@ -397,6 +397,33 @@ def test_engine_ingest_requires_live_store():
         pipe.queue_delta(Delta.append(_raw(1, 8)))
 
 
+def test_drain_waits_for_the_delta_being_applied():
+    """ingest_step takes a delta off the backlog before applying it:
+    drain() must not report the backlog done until the delta has landed."""
+    import threading
+
+    live = VersionedStore(make_synthetic_store(64, 8, seed=13), shards=8)
+    pipe = _sparse_pipe(live)
+    started, release = threading.Event(), threading.Event()
+    ingest = pipe.ingest
+
+    def slow_ingest(delta):
+        started.set()
+        assert release.wait(30.0)
+        return ingest(delta)
+
+    pipe.ingest = slow_ingest
+    with AsyncFrontend(pipe) as fe:
+        fe.ingest(Delta.update([5], _raw(1, 8)))
+        assert started.wait(30.0)
+        assert pipe.pending_deltas == 0
+        assert not fe.drain(0.2)
+        release.set()
+        assert fe.drain(30.0)
+        assert fe.metrics["ingested"] == 1
+        assert live.version == 1
+
+
 def test_frontend_applies_deltas_in_idle_slot():
     """Writes ride the flush worker's idle slot: submits and ingests
     interleave through AsyncFrontend, drain() waits out the delta
