@@ -17,6 +17,11 @@ from repro.kernels import (
     ref,
     xor_fold,
 )
+from repro.kernels.xor_fold import (
+    VMEM_BUDGET_BYTES,
+    fold_blocks,
+    fold_vmem_bytes,
+)
 
 SHAPES = [
     # (n records, record_bytes, q queries)
@@ -30,6 +35,11 @@ SHAPES = [
 
 MASK_DTYPES = [jnp.uint8, jnp.int32, jnp.bool_]
 
+# xor_fold at its default blocks on a scaled CT store (1,536 B records, so
+# W = 384 and BN = 2048): n not a multiple of BN, every bucket the Chor
+# cells run plus an odd one, and a store smaller than one record block
+CT_FOLD_SHAPES = [(2348, 1536, q) for q in (1, 2, 3, 8)] + [(100, 1536, 2)]
+
 
 def _case(n, rb, q, seed=0):
     store = make_synthetic_store(n=n, record_bytes=rb, seed=seed)
@@ -38,7 +48,7 @@ def _case(n, rb, q, seed=0):
     return store, mask
 
 
-@pytest.mark.parametrize("n,rb,q", SHAPES)
+@pytest.mark.parametrize("n,rb,q", SHAPES + CT_FOLD_SHAPES)
 def test_xor_fold_matches_ref(n, rb, q):
     store, mask = _case(n, rb, q)
     want = np.asarray(ref.xor_fold_ref(store.packed, mask))
@@ -48,12 +58,41 @@ def test_xor_fold_matches_ref(n, rb, q):
 
 @pytest.mark.parametrize("dtype", MASK_DTYPES)
 def test_xor_fold_mask_dtypes(dtype):
-    store, mask = _case(128, 16, 7)
-    want = np.asarray(ref.xor_fold_ref(store.packed, mask))
-    got = np.asarray(
-        xor_fold(store.packed, mask.astype(dtype), interpret=True)
-    )
-    np.testing.assert_array_equal(got, want)
+    for n, rb, q in [(128, 16, 7), (2348, 1536, 3)]:
+        store, mask = _case(n, rb, q)
+        want = np.asarray(ref.xor_fold_ref(store.packed, mask))
+        got = np.asarray(
+            xor_fold(store.packed, mask.astype(dtype), interpret=True)
+        )
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("n,w,q", [
+    (10**6, 384, 8),    # the CT store, a full Chor bucket
+    (10**6, 384, 1),
+    (2348, 384, 3),
+    (100, 384, 2),      # a store smaller than one record block
+    (70_000, 128, 33),
+    (5000, 12, 5),      # narrower than one lane block
+    (3000, 200, 3),     # wider than 128 words, not a multiple of 128
+    (10**5, 16384, 2),  # 64 KiB records: too wide for a whole-record slab
+])
+def test_fold_blocks_rule(n, w, q):
+    bq, bn, bw = fold_blocks(n, w, q)
+    assert bq == min(8, q)
+    # whole 128-row multiples, or one block of 8-row tiles over the store
+    assert bn % 128 == 0 or bn == -(-n // 8) * 8
+    assert fold_vmem_bytes(bq, bn, bw) <= VMEM_BUDGET_BYTES
+    whole = w <= 128 or (w % 128 == 0
+                         and fold_vmem_bytes(bq, 128, w) <= VMEM_BUDGET_BYTES)
+    assert bw == (w if whole else 128)
+    if (n, w) == (10**6, 384):
+        assert bw == w
+        steps = -(-q // bq) * -(-w // bw) * -(-n // bn)
+        assert steps < 1000, steps
+        assert bn * bw * 4 >= 1 << 20  # at least 1 MiB of store a step
+    if w == 16384:
+        assert bw == 128
 
 
 @pytest.mark.parametrize("block_q,block_n,block_w", [(4, 64, 32), (8, 256, 128), (16, 32, 8)])

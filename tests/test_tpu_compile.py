@@ -64,10 +64,15 @@ def _compile(fn, sharding, *shapes):
 
 
 KERNELS = {
-    "xor_fold": (
-        lambda db, m: xor_fold(db, m),
-        [((N, W), jnp.uint32), ((8, N), jnp.uint8)],
-    ),
+    # the default blocks at each bucket the Chor cells run (8 keeps the
+    # bare name)
+    **{
+        "xor_fold" + ("" if q == 8 else f"_q{q}"): (
+            lambda db, m: xor_fold(db, m),
+            [((N, W), jnp.uint32), ((q, N), jnp.uint8)],
+        )
+        for q in (1, 2, 4, 8)
+    },
     "parity_matmul": (
         lambda m, planes: parity_matmul(m, planes),
         [((128, N_VMEM), jnp.uint8), ((N_VMEM, 32 * W), jnp.float32)],
