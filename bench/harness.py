@@ -20,9 +20,11 @@ the answer.
 
 ``correct`` also holds the run to the guarantee its configuration states
 (:func:`guarantee_checks`): every answered batch, warm-up and window, went
-to all d servers, and each server's mask for each query has the share of
-ones the scheme states; and the program prices a lookup at the stated
-(epsilon, delta).
+to all d servers on the stated wire; on a mask wire each server's mask
+for each query has the share of ones the scheme states; on an index wire
+(Direct Requests) each lookup asks p distinct records holding the real
+one once, and the dummies and the real index's slot spread as Algorithm
+4.1 says; and the program prices a lookup at the stated (epsilon, delta).
 
 The calls into each layer are wrapped from here (spans from the
 benchmark's own files; the program has none): the answer stage always, to
@@ -33,6 +35,7 @@ reconstruction, planning, execution and the idle-slot jobs, each in a
 
 from __future__ import annotations
 
+import functools
 import gc
 import importlib
 import importlib.util
@@ -188,15 +191,21 @@ class Probe:
         self._ones = jax.jit(
             lambda p: jnp.sum(p != 0, axis=-1, dtype=jnp.int32)
         )
-        #: per answered batch: the servers it went to, and each server's
-        #: count of ones per query (a device array until the run is checked)
+        self._requests = jax.jit(functools.partial(
+            request_counts, n=int(pipe.store.n),
+            bins=load_reference("requests").BINS))
+        #: per answered batch: the servers it went to, its wire, and what
+        #: the guarantee checks read from it: each server's count of ones
+        #: per query (mask), or the request counts of
+        #: ``references/requests.py`` (index); device arrays until the run
+        #: is checked
         self.asked: List[tuple] = []
-        ask, ret = faults.breaker(fault)
+        ask, ret = faults.breaker(fault, n=int(pipe.store.n))
 
         answer = backend.answer_batch
 
-        def answer_batch(routed, **kw):
-            routed = ask(routed)
+        def answer_batch(sent, **kw):
+            routed = ask(sent)
             bucket = int(routed.payload.shape[1])
             with self._mu:
                 self.buckets[bucket] = self.buckets.get(bucket, 0) + 1
@@ -206,12 +215,17 @@ class Probe:
                 stats = self._answer_stats(routed)
                 with jax.profiler.TraceAnnotation("bench.answer_batch", **stats):
                     out = answer(routed, **kw)
-            # after the span, and not waited for: the count runs behind
+            # after the span, and not waited for: the reduction runs behind
             # the answer on the device and is read once the run is over
-            ones = self._ones(routed.payload) if routed.kind == "mask" else None
+            if routed.kind == "mask":
+                reading = self._ones(routed.payload)
+            else:
+                reading = dict(self._requests(routed.payload, routed.q_idx),
+                               k=int(routed.payload.shape[2]))
             with self._mu:
-                self.asked.append((tuple(routed.servers), ones))
-            return ret(out)
+                self.asked.append((tuple(routed.servers), routed.kind,
+                                   reading))
+            return ret(out, sent)
 
         backend.answer_batch = answer_batch
 
@@ -229,7 +243,8 @@ class Probe:
     def _answer_stats(self, routed) -> dict:
         """Shape facts of one answer call: servers, bucket, records and
         words; for a sparse wire also the records any of the bucket's
-        masks select, per server, summed."""
+        masks select, per server, summed; for an index wire the slots
+        each server is asked per query (k)."""
         store = self.fe.pipeline.store
         stats = {
             "servers": len(routed.servers),
@@ -240,6 +255,8 @@ class Probe:
         }
         if routed.kind == "mask" and getattr(routed, "theta", None) is not None:
             stats["union_records"] = int(self._union(routed.payload).sum())
+        if routed.kind == "index":
+            stats["k"] = int(routed.payload.shape[2])
         return stats
 
     def _span(self, name: str, fn: Callable, sync: Optional[Callable] = None):
@@ -304,6 +321,7 @@ class Session:
         cfg = spec["config"]
         prog = cfg["program"]
         self.n = int(prog["n_records"])
+        stated_wire(cfg["guarantee"], cfg["limits"], self.n)
         self.nbytes = int(prog["record_bytes"])
         if self.nbytes % 4:
             raise ValueError("record_bytes must be a whole number of words")
@@ -514,31 +532,118 @@ def check(reference, seed: int, words: int, nbytes: int,
     return ok
 
 
+def request_counts(payload, q_idx, *, n: int, bins: int) -> dict:
+    """On the device, what ``references/requests.py``'s ``reduce_batch``
+    counts for one index-wire batch (``payload [d, B, k]``, ``q_idx [B]``),
+    with the number of lookups that break Algorithm 4.1's rule in place
+    of their list."""
+    import jax.numpy as jnp
+
+    d, b, k = payload.shape
+    x = jnp.transpose(payload, (1, 0, 2)).reshape(b, d * k)
+    q = q_idx.astype(x.dtype)[:, None]
+    real = x == q
+    inside = (x >= 0) & (x < n)
+    srt = jnp.sort(x, axis=1)
+    distinct = jnp.all(srt[:, 1:] != srt[:, :-1], axis=1)
+    sound = jnp.all(inside, axis=1) & distinct & (jnp.sum(real, axis=1) == 1)
+    dummy = inside & ~real
+    w_store = -(-n // bins)
+    w_offset = -(-(n - 1) // bins)
+    offset = jnp.where(x >= q, x - q, x - q + n)
+
+    def hist(at, weight):
+        onehot = at[..., None] == jnp.arange(bins, dtype=at.dtype)
+        return jnp.sum(onehot & weight[..., None],
+                       axis=tuple(range(at.ndim)), dtype=jnp.int32)
+
+    return {
+        "violations": jnp.sum(~sound, dtype=jnp.int32),
+        "lookups": jnp.int32(b),
+        "dummies": hist(jnp.clip(x // w_store, 0, bins - 1), dummy),
+        "reals": hist(jnp.clip(q_idx // w_store, 0, bins - 1),
+                      jnp.ones((b,), bool)),
+        "offsets": hist(jnp.clip((offset - 1) // w_offset, 0, bins - 1),
+                        dummy),
+        "servers": jnp.sum(real.reshape(b, d, k), axis=(0, 2),
+                           dtype=jnp.int32),
+    }
+
+
+def stated_wire(guarantee: dict, limits: dict, n: int) -> str:
+    """The wire the guarantee states, ``mask`` (``guarantee.masks``: Chor
+    or Sparse masks) or ``index`` (``guarantee.requests``: Direct
+    Requests, p indices a lookup); refuses a guarantee that states
+    neither or both, an unknown kind, a p that Algorithm 4.1 cannot run,
+    or a limit it needs and lacks."""
+    stated = [key for key in ("masks", "requests") if key in guarantee]
+    if len(stated) != 1:
+        raise ValueError("the guarantee must state exactly one of 'masks' "
+                         f"and 'requests'; it states {stated}")
+    d = int(guarantee["d"])
+    if stated == ["masks"]:
+        spec = guarantee["masks"]
+        load_reference("masks").density(spec["kind"], d,
+                                        float(spec.get("theta", 0.5)))
+        need = "mask_density_z"
+    else:
+        spec = guarantee["requests"]
+        if spec.get("kind") != "direct":
+            raise ValueError(f"no request guarantee of kind "
+                             f"{spec.get('kind')!r}; have 'direct'")
+        load_reference("requests").check_stated(int(spec["p"]), d, n)
+        need = "request_spread_z"
+    if need not in limits:
+        raise ValueError(f"the configuration's limits lack {need!r}")
+    return "mask" if stated == ["masks"] else "index"
+
+
 def guarantee_checks(guarantee: dict, asked: List[tuple], price: tuple,
                      n: int, limits: dict) -> dict:
     """The numbers that hold a run to the guarantee its configuration
-    states, each with its limit: batches that did not go to all d
-    servers; how far any server's mask for any query lies from the
+    states, each with its limit. Always: batches that did not go to all d
+    servers on the stated wire (``short_batches``), and whether the
+    program's price per lookup differs from the stated (epsilon, delta).
+    On a mask wire, how far any server's mask for any query lies from the
     stated density, in standard deviations of its Binomial(n, p) count
-    (``references/masks.py``); and whether the program's price per lookup
-    differs from the stated (epsilon, delta)."""
-    masks = load_reference("masks")
+    (``references/masks.py``). On an index wire, the lookups whose p
+    indices break Algorithm 4.1's rule, and the largest |z| of the pooled
+    request counts (``references/requests.py``)."""
+    wire = stated_wire(guarantee, limits, n)
     d = int(guarantee["d"])
-    short = sum(len(servers) != d or (ones is not None and ones.shape[0] != d)
-                for servers, ones in asked)
-    spec = guarantee["masks"]
-    p = masks.density(spec["kind"], d, float(spec.get("theta", 0.5)))
-    z = [np.abs(masks.row_z(np.asarray(ones), n, p)).max()
-         for _, ones in asked if ones is not None]
+    mine = [(servers, reading) for servers, kind, reading in asked
+            if kind == wire]
+    short = len(asked) - len(mine) + sum(len(servers) != d for servers, _ in mine)
+    checks = {}
+    if wire == "mask":
+        masks = load_reference("masks")
+        spec = guarantee["masks"]
+        p = masks.density(spec["kind"], d, float(spec.get("theta", 0.5)))
+        short += sum(ones.shape[0] != d for _, ones in mine)
+        z = [np.abs(masks.row_z(ones, n, p)).max() for _, ones in mine]
+        checks["short_batches"] = {"value": int(short), "limit": 0}
+        checks["mask_density_z"] = {"value": float(max(z, default=0.0)),
+                                    "limit": float(limits["mask_density_z"])}
+    else:
+        requests = load_reference("requests")
+        p = int(guarantee["requests"]["p"])
+        short += sum(len(c["servers"]) != d or c["k"] != p // d
+                     for _, c in mine)
+        counts = [{k: v for k, v in c.items() if k not in ("violations", "k")}
+                  for _, c in mine]
+        spread = requests.spread_z(requests.pool(counts), n, p) \
+            if counts else 0.0
+        checks["short_batches"] = {"value": int(short), "limit": 0}
+        checks["request_violations"] = {
+            "value": int(sum(int(c["violations"]) for _, c in mine)),
+            "limit": 0}
+        checks["request_spread_z"] = {
+            "value": spread, "limit": float(limits["request_spread_z"])}
     stated = (float(guarantee["epsilon_per_lookup"]),
               float(guarantee["delta_per_lookup"]))
-    return {
-        "short_batches": {"value": int(short), "limit": 0},
-        "mask_density_z": {"value": float(max(z, default=0.0)),
-                           "limit": float(limits["mask_density_z"])},
-        "price_mismatch": {"value": int(tuple(map(float, price)) != stated),
-                           "limit": 0},
-    }
+    checks["price_mismatch"] = {"value": int(tuple(map(float, price)) != stated),
+                                "limit": 0}
+    return checks
 
 
 def device_info(jax, chips: int) -> dict:
@@ -592,8 +697,8 @@ def run_cell(bench: dict, name: str, *, seed: int, seconds: float,
         if sess.memo_hits is not None:
             log(f"memo: {sess.memo_hits[0]} of {sess.memo_hits[1]} repeated "
                 f"warm-up lookups served from the L1 memo")
-        asked = [(servers, None if ones is None else np.asarray(ones))
-                 for servers, ones in sess.probe.asked]
+        asked = [(servers, kind, jax.tree.map(np.asarray, reading))
+                 for servers, kind, reading in sess.probe.asked]
         device = device_info(jax, int(cell["chips"]))
         log(f"memory_peak_bytes {device['memory_peak_bytes']}")
         sess.close()

@@ -7,7 +7,10 @@ an error). Per server and batch of B queries over n records of W words:
 * a dense mask (Chor): the store once (n * W * 4), the masks as packed
   bits (B * n / 8) and the outputs (B * W * 4);
 * a sparse mask (Sparse-PIR): the records any of the B masks selects
-  (the union, at most the store once), the packed masks and the outputs.
+  (the union, at most the store once), the packed masks and the outputs;
+* an index wire (Direct Requests, k slots a query): each of the B * k
+  records gathered, read and written once, and its index:
+  B * k * (2 * W * 4 + 4).
 
 The answer span's stats give the shapes and, for a sparse wire, the
 union summed over servers. Denominator: device time inside the
@@ -19,6 +22,8 @@ replaced (device trace)."""
 def least_bytes(stats) -> float:
     servers, b = int(stats["servers"]), int(stats["bucket"])
     n, w = int(stats["n"]), int(stats["words"])
+    if stats["kind"] == "index":
+        return servers * b * int(stats["k"]) * (2 * w * 4 + 4)
     if stats["kind"] != "mask":
         raise ValueError(f"no least-bytes rule for a {stats['kind']} wire")
     records = int(stats["union_records"]) if "union_records" in stats \

@@ -100,6 +100,17 @@ def test_answer_roofline_dense_and_sparse():
     assert got == pytest.approx(100 * least / 819e9)
 
 
+def test_answer_roofline_index_wire():
+    # Direct Requests: each server gathers B * k records, reads and writes
+    # each once, and reads its index
+    n, w, d, b, k = 10**6, 384, 100, 8, 1
+    span = answer_span(0, 1e9, servers=d, bucket=b, n=n, words=w,
+                       kind="index", k=k)
+    least = d * b * k * (2 * w * 4 + 4)
+    got = harness.load_reader("answer_roofline")(run(trace=red([span])))
+    assert got == pytest.approx(100 * least / 819e9)
+
+
 def test_answer_roofline_counts_device_time_inside_its_spans_only():
     span = answer_span(0, 1e9, servers=1, bucket=1, n=1000, words=4,
                        kind="mask")
