@@ -64,7 +64,12 @@ import xplane  # noqa: E402
 DRAIN_S = 60.0
 #: the longest the idle slot may take to settle after a warm pass
 SETTLE_S = 900.0
-COUNTERS = ("queries", "cache_hits", "batches", "padded", "truncated")
+#: the program's counters a window reads: the pipeline's work counts, and
+#: the wait counters that split a lookup's latency (submit -> admission,
+#: enqueue -> cut, planning -> execute, execute), on the scheduler's clock
+COUNTERS = ("queries", "cache_hits", "batches", "padded", "truncated",
+            "admit_wait_s", "admit_count", "queue_wait_s", "dequeued",
+            "dispatch_wait_s", "execute_s")
 
 
 def load_json(path: Path) -> dict:
@@ -136,29 +141,48 @@ def start(chips: int):
 
 
 class CompileLog:
-    """Counts what JAX compiles or loads from its cache, by phase."""
+    """Counts what JAX compiles or loads from its cache, by phase, and
+    names each program compiled (``names``: count by the ``fun_name`` JAX
+    gives the compile event; ``?`` where it gives none)."""
 
     def __init__(self) -> None:
         import jax
 
         self.counts = {"traces": 0, "compiles": 0, "cache_hits": 0,
                        "compile_s": 0.0}
+        self.names: Dict[str, int] = {}
+        self._mu = threading.Lock()
         jax.monitoring.register_event_duration_secs_listener(self._duration)
         jax.monitoring.register_event_listener(self._event)
 
-    def _duration(self, event: str, secs: float, **_) -> None:
-        if event == "/jax/core/compile/jaxpr_trace_duration":
-            self.counts["traces"] += 1
-        elif event == "/jax/core/compile/backend_compile_duration":
-            self.counts["compiles"] += 1
-            self.counts["compile_s"] += secs
+    def _duration(self, event: str, secs: float, fun_name=None, **_) -> None:
+        with self._mu:
+            if event == "/jax/core/compile/jaxpr_trace_duration":
+                self.counts["traces"] += 1
+            elif event == "/jax/core/compile/backend_compile_duration":
+                self.counts["compiles"] += 1
+                self.counts["compile_s"] += secs
+                name = fun_name or "?"
+                self.names[name] = self.names.get(name, 0) + 1
 
     def _event(self, event: str, **_) -> None:
         if event == "/jax/compilation_cache/cache_hits":
-            self.counts["cache_hits"] += 1
+            with self._mu:
+                self.counts["cache_hits"] += 1
 
     def snapshot(self) -> dict:
-        return dict(self.counts)
+        with self._mu:
+            return dict(self.counts, names=dict(self.names))
+
+    @staticmethod
+    def since(before: dict, after: dict) -> dict:
+        """What was counted between two snapshots, with the programs
+        compiled in between by name."""
+        out = {k: after[k] - before[k] for k in before if k != "names"}
+        out["names"] = {k: c - before["names"].get(k, 0)
+                        for k, c in after["names"].items()
+                        if c > before["names"].get(k, 0)}
+        return out
 
 
 class Probe:
@@ -180,6 +204,8 @@ class Probe:
         self.fe = fe
         self.traced = traced
         self.buckets: Dict[int, int] = {}
+        #: per bucket, the shape and dtype of the payload the pipeline sent
+        self.payloads: Dict[int, tuple] = {}
         self.idle_running = 0
         self.idle_last: Dict[str, Optional[int]] = {}
         self._mu = threading.Lock()
@@ -209,6 +235,8 @@ class Probe:
             bucket = int(routed.payload.shape[1])
             with self._mu:
                 self.buckets[bucket] = self.buckets.get(bucket, 0) + 1
+                self.payloads[bucket] = (sent.payload.shape,
+                                         sent.payload.dtype)
             if not self.traced:
                 out = answer(routed, **kw)
             else:
@@ -340,8 +368,10 @@ class Session:
         self.jax = jax
 
     def counters(self) -> dict:
+        """:data:`COUNTERS` as the program has them now; a name the
+        program lacks (one older than its wait counters) is left out."""
         m = self.fe.metrics
-        return {k: m[k] for k in COUNTERS}
+        return {k: m[k] for k in COUNTERS if k in m}
 
     def _warm_bucket(self, mix: dict, b: int, tag: str) -> None:
         """Serve one batch of exactly ``b`` requests through the pipeline's
@@ -428,7 +458,9 @@ class Session:
         self.fe.start()
         self._settle()
         # a batch of b lookups in a bucket B > b takes its b answers out of
-        # the [B, W] reconstruction with one eager slice, a program of its
+        # the [B, W] reconstruction, and the b columns of its payload that
+        # the memo keeps (where a column is small enough, as Direct
+        # Requests' p ids are), with one eager slice each, a program of its
         # own for each b; run those slices here instead of a whole batch of
         # every size through all d servers
         jnp = self.jax.numpy
@@ -436,6 +468,8 @@ class Session:
             size = next(x for x in buckets if x >= b)
             if size != b:
                 jnp.zeros((size, self.words), jnp.uint32)[:b].block_until_ready()
+                shape, dtype = self.probe.payloads[size]
+                jnp.zeros(shape, dtype)[:, :b].block_until_ready()
         return buckets
 
     def _submit(self, client: str, indices: tuple):
@@ -477,7 +511,7 @@ class Session:
             "driver": drv,
             "counters_open": c0,
             "counters_close": c1,
-            "compiles": {k: k1[k] - k0[k] for k in k0},
+            "compiles": CompileLog.since(k0, k1),
             "buckets": {b: b1.get(b, 0) - b0.get(b, 0) for b in b1
                         if b1.get(b, 0) - b0.get(b, 0)},
             "idle_jobs": {k: idle1[k] - idle0[k] for k in ("prefilled",
@@ -672,6 +706,7 @@ def run_cell(bench: dict, name: str, *, seed: int, seconds: float,
     buckets = sess.warm(mix)
     k_setup = compiles.snapshot()
     setup_s = time.perf_counter() - t_start
+    del k_setup["names"]  # hundreds of programs; the window's are named
     log(f"setup_s {setup_s} buckets {buckets} compiles {k_setup}")
 
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None

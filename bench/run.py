@@ -5,8 +5,9 @@
 Needs the TPU chips the cell asks for, and exits with code 3 and no result
 where JAX finds fewer or another platform. Earlier lines on standard
 output give the set-up, the plans the answer stage executed, what JAX
-compiled inside the window (it should be nothing), the generator's
-lateness, the batches and padding, and the device's peak bytes. The last
+compiled inside the window, by name (it should be nothing), the
+generator's lateness, the program's counters (its wait counters among
+them), the batches and padding, and the device's peak bytes. The last
 line is one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
 per-layer ones), ``device`` and ``checks``, the number compared with its

@@ -11,11 +11,13 @@ backlog (lookups sent and not yet answered) at each quarter of the window,
 and the backlog's growth over the window: the least-squares slope of the
 backlog, sampled every half second, times the window's length.
 
-The rule: a rate holds when its backlog grows by fewer than
-``GROWTH_LIMIT`` lookups over the window; the knee is the highest rate
-that holds with every lower rate tried holding too. The last line gives the knee and four fifths of
-it, the rate an open-loop cell below the knee is run at. Used once, to
-fix the rate the cell's mix file holds; the benchmark's runs never call it.
+The rule: a rate holds when its backlog grows over the window by less
+than :func:`growth_limit` of the lookups the window sent, the larger of
+``GROWTH_FLOOR`` lookups and ``GROWTH_SHARE`` of them; the knee is the
+highest rate that holds with every lower rate tried holding too. The
+last line gives the knee and four fifths of it, the rate an open-loop
+cell below the knee is run at. Used once, to fix the rate the cell's mix
+file holds; the benchmark's runs never call it.
 """
 
 from __future__ import annotations
@@ -32,6 +34,8 @@ import os  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
 
+import numpy as np  # noqa: E402
+
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
 
@@ -39,33 +43,52 @@ ROOT = HERE.parent
 #: seconds between two readings of the backlog
 SAMPLE_S = 0.5
 
-#: backlog growth over a window, in lookups, at which a rate no longer
-#: holds: rates well below the knee read within one lookup of 0, and a
-#: queue that grows by a few lookups through the window is not sustained
-GROWTH_LIMIT = 2.0
+#: the least backlog growth over a window, in lookups, at which a rate no
+#: longer holds: at a few lookups a second, rates well below the knee read
+#: within one lookup of 0, and a queue that grows by a few lookups through
+#: the window is not sustained
+GROWTH_FLOOR = 2.0
+#: above the floor, the limit as a share of the lookups the window sent.
+#: The fitted growth of a queue that holds is noise on the scale of the
+#: backlog's swing, one batch: a batch of ``rate x period`` lookups, cut
+#: every ``period`` seconds. Fitted from ``seconds / SAMPLE_S`` readings,
+#: its sd is about ``0.29 x batch x sqrt(12 SAMPLE_S / seconds)`` lookups:
+#: 0.08% of what was sent at a 0.3 s period over 40 s, so 1% stands over
+#: ten sds off. A queue that outgrows 1% of its load completes less than
+#: 99% of what it is offered: the knee so found lies within about 1% of
+#: the rate the system completes. Below 200 lookups a window the floor
+#: rules, as at ``ct_chor.poisson``'s sweep.
+GROWTH_SHARE = 0.01
 
 
-def backlog(lookups, t) -> int:
-    return sum(lk.t_submit <= t for lk in lookups) - sum(
-        (not math.isnan(lk.t_done)) and lk.t_done <= t for lk in lookups)
+def growth_limit(sent: int) -> float:
+    """The backlog growth, in lookups, at which a window that sent
+    ``sent`` lookups no longer holds."""
+    return max(GROWTH_FLOOR, GROWTH_SHARE * sent)
+
+
+def backlog(lookups, t):
+    """Lookups sent and not yet answered at each time of ``t``."""
+    sent = np.sort([lk.t_submit for lk in lookups])
+    done = np.sort([lk.t_done for lk in lookups if not math.isnan(lk.t_done)])
+    return (np.searchsorted(sent, t, side="right")
+            - np.searchsorted(done, t, side="right"))
 
 
 def growth(lookups, t_open: float, seconds: float) -> float:
     """How much the backlog grows over the window, by a straight line
     fitted to it every ``SAMPLE_S`` seconds."""
-    t = [t_open + SAMPLE_S * (i + 1) for i in range(int(seconds / SAMPLE_S))]
-    y = [backlog(lookups, x) for x in t]
-    tm, ym = sum(t) / len(t), sum(y) / len(y)
-    slope = sum((a - tm) * (b - ym) for a, b in zip(t, y)) / sum(
-        (a - tm) ** 2 for a in t)
-    return slope * seconds
+    t = t_open + SAMPLE_S * np.arange(1, int(seconds / SAMPLE_S) + 1)
+    y = backlog(lookups, t)
+    tc = t - t.mean()
+    return float(np.dot(tc, y - y.mean()) / np.dot(tc, tc) * seconds)
 
 
 def knee(rows) -> float:
     """The highest rate that holds, every lower rate holding too."""
     best = None
     for row in sorted(rows, key=lambda r: r["rate_qps"]):
-        if row["backlog_growth"] >= GROWTH_LIMIT:
+        if row["backlog_growth"] >= growth_limit(row["sent"]):
             break
         best = row["rate_qps"]
     return best
@@ -86,7 +109,6 @@ def main() -> int:
     except harness.NoChip as e:
         print(f"sweep.py: {e}", file=sys.stderr)
         return 3
-    import numpy as np
 
     with open(ROOT / "BENCHMARK.json") as f:
         spec = harness.cell_spec(json.load(f), args.workload)
@@ -102,8 +124,8 @@ def main() -> int:
         lat = [(lk.t_done - lk.t_sched) * 1e3 for lk in drv.lookups]
         done_in = sum(drv.t_open <= lk.t_done <= drv.t_close
                       for lk in drv.lookups)
-        quarters = [backlog(drv.lookups, drv.t_open + q * args.seconds / 4)
-                    for q in (1, 2, 3, 4)]
+        quarters = backlog(drv.lookups, drv.t_open + np.arange(1, 5)
+                           * args.seconds / 4).tolist()
         c0, c1 = win["counters_open"], win["counters_close"]
         row = {
             "rate_qps": rate,
@@ -113,6 +135,7 @@ def main() -> int:
             "p95_ms": float(np.percentile(lat, 95)),
             "backlog_quarters": quarters,
             "backlog_growth": growth(drv.lookups, drv.t_open, args.seconds),
+            "growth_limit": growth_limit(len(drv.lookups)),
             "batches": c1["batches"] - c0["batches"],
             "compiles": win["compiles"],
             "unanswered_at_drain": win["unanswered_at_drain"],

@@ -140,3 +140,42 @@ def test_lookups_per_batch():
             counters_close={"queries": 40, "cache_hits": 3, "batches": 9})
     assert harness.load_reader("lookups_per_batch.poisson")(r) \
         == pytest.approx(28 / 7)
+
+
+def waits(**close):
+    """A run whose window moved the program's wait counters from the open
+    snapshot by ``close``."""
+    c0 = {"queries": 50, "cache_hits": 2, "batches": 30,
+          "admit_wait_s": 0.25, "admit_count": 50, "queue_wait_s": 40.0,
+          "dequeued": 48, "dispatch_wait_s": 9.0, "execute_s": 30.0}
+    c1 = dict(c0)
+    for k, v in close.items():
+        c1[k] += v
+    return run(counters_open=c0, counters_close=c1)
+
+
+def test_admit_wait_ms():
+    r = waits(admit_wait_s=0.012, admit_count=100)
+    assert harness.load_reader("admit_wait_ms.poisson")(r) \
+        == pytest.approx(0.12)
+    assert harness.load_reader("admit_wait_ms.poisson")(
+        waits(admit_wait_s=0.5)) is None
+
+
+def test_queue_wait_ms():
+    r = waits(queue_wait_s=18.0, dequeued=96)
+    assert harness.load_reader("queue_wait_ms.poisson")(r) \
+        == pytest.approx(187.5)
+    assert harness.load_reader("queue_wait_ms.poisson")(
+        waits(queue_wait_s=1.0)) is None
+
+
+def test_dispatch_wait_ms():
+    r = waits(dispatch_wait_s=4.8, dequeued=96)
+    assert harness.load_reader("dispatch_wait_ms.poisson")(r) \
+        == pytest.approx(50.0)
+    # a program older than the wait counters: the reader finds nothing
+    old = {k: v for k, v in r["counters_close"].items()
+           if k != "dispatch_wait_s"}
+    assert harness.load_reader("dispatch_wait_ms.poisson")(
+        dict(r, counters_close=old)) is None

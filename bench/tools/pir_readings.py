@@ -13,7 +13,8 @@ Prints run.py's result line, then one line ``PIR {json}``:
 * ``finalize_ms``, ``mean_ms[<span>]``, ``count[<span>]``,
   ``total_s[<span>]``: span durations, counts and covered seconds;
 * ``idle_s_in[<span>]``: device-idle seconds inside a span's cover;
-* ``admit_wait_ms``, ``queue_wait_ms``, ``dispatch_wait_ms``,
+* ``admit_wait_ms``, ``queue_wait_ms``, ``dispatch_wait_ms`` (the
+  readers of ``bench/metrics/<name>.poisson.py`` beside this tool), and
   ``execute_ms``: the window's wait counters per request, beside
   ``mean_latency_ms`` and ``mean_lateness_ms`` from the traffic generator;
 * ``idle_by_label_*``: the window's device-idle seconds by the innermost
@@ -24,21 +25,24 @@ Prints run.py's result line, then one line ``PIR {json}``:
 * ``end_to_end_traced``: the end-to-end readers on a traced run, which
   run.py reports only untraced.
 
-It wraps ``harness.Session.counters``, ``xplane.load`` and
-``harness.load_reader`` in its own process to see what run.py throws
-away; the benchmark's files are not changed. ``--root`` runs the
-checkout at DIR (say, a parent commit unpacked beside this one); on a
-program without the counters their readings are left out. ``--tiny``
-runs a CPU-sized cell for rehearsal."""
+It wraps ``harness.Session.counters`` (for a checkout whose harness does
+not read the wait counters), ``xplane.load`` and ``harness.load_reader``
+in its own process to see what run.py throws away; the benchmark's files
+are not changed. ``--root`` runs the checkout at DIR (say, a parent
+commit unpacked beside this one); on a program without the counters
+their readings are left out. ``--tiny`` runs a CPU-sized cell for
+rehearsal."""
 
 from __future__ import annotations
 
 import argparse
+import importlib.util
 import json
 import math
 import os
 import sys
 import time
+from pathlib import Path
 
 T_START = time.perf_counter()
 
@@ -51,6 +55,21 @@ NAMED = ("pir.plan", "pir.query_gen", "pir.execute", "pir.answer",
          "pir.unpack", "pir.cache_insert", "pir.admit", "pir.wait.inflight",
          "pir.wait.arrivals", "pir.idle.prefill", "pir.idle.autotune")
 IDLE_IN = ("pir.answer.server", "pir.answer", "pir.finalize", "pir.plan")
+#: the wait readers of this checkout's ``bench/metrics/``, by the key printed
+WAIT_READERS = {"admit_wait_ms": "admit_wait_ms.poisson",
+                "queue_wait_ms": "queue_wait_ms.poisson",
+                "dispatch_wait_ms": "dispatch_wait_ms.poisson"}
+METRICS = Path(__file__).resolve().parents[1] / "metrics"
+
+
+def own_reader(name):
+    """``read`` of ``bench/metrics/<name>.py`` beside this tool, whatever
+    checkout ``--root`` runs."""
+    spec = importlib.util.spec_from_file_location(
+        f"pir_readings_{name.replace('.', '_')}", METRICS / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
 
 
 def label_sweep(spans, gaps, window_span="bench.window", skip_bench=False):
@@ -108,14 +127,10 @@ def window_waits(run):
     c0, c1 = run["counters_open"], run["counters_close"]
     dc = {k: c1[k] - c0[k] for k in c1 if k in c0}
     out = {"window_counters": dc}
-
-    def per(a, b):
-        return dc[a] / dc[b] * 1e3 if dc.get(b) and a in dc else None
-
-    out["admit_wait_ms"] = per("admit_wait_s", "admit_count")
-    out["queue_wait_ms"] = per("queue_wait_s", "dequeued")
-    out["dispatch_wait_ms"] = per("dispatch_wait_s", "dequeued")
-    out["execute_ms"] = per("execute_s", "dequeued")
+    for key, name in WAIT_READERS.items():
+        out[key] = own_reader(name)(run)
+    out["execute_ms"] = dc["execute_s"] / dc["dequeued"] * 1e3 \
+        if dc.get("dequeued") and "execute_s" in dc else None
     done = [lk for lk in run["lookups"] if not math.isnan(lk.t_done)]
     if done:
         out["mean_latency_ms"] = sum(lk.t_done - lk.t_sched for lk in done) / len(done) * 1e3
